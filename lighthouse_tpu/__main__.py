@@ -157,6 +157,8 @@ def build_parser():
 def main(argv=None):
     parser = build_parser()
     args = parser.parse_args(argv)
+    from .utils import compile_cache
+    compile_cache.configure()
 
     if args.testnet_dir:
         from .specs.networks import load_testnet_dir

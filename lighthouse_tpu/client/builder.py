@@ -93,7 +93,8 @@ class ClientBuilder:
         cfg = self.config
         client = Client()
         client.env = self.env
-        bls.set_backend(cfg.crypto_backend)
+        # compile a device backend's programs now, not in the first batches
+        bls.set_backend(cfg.crypto_backend).precompile()
 
         # store
         if cfg.datadir:

@@ -35,23 +35,17 @@ _USE_HOST_HASH = None
 
 
 def _use_host_hash() -> bool:
-    """True when the big-column rehash should run on the HOST (SHA-NI
-    C++ batch hasher) instead of the XLA kernels: no accelerator attached
-    (CPU backend) and the native library builds.  This mirrors the
-    reference's sha2-asm host path; the device kernels stay the TPU
-    path."""
+    """True when the big-column rehash runs on the HOST (SHA-NI C++
+    batch hasher) instead of the XLA kernels: chosen by platform alone —
+    the CPU backend hashes on the host (the reference's sha2-asm path),
+    an accelerator on the device."""
     global _USE_HOST_HASH
     if _USE_HOST_HASH is None:
-        from ..utils import native_hash as nh
-        if nh.get_lib() is None:
-            _USE_HOST_HASH = False
-        else:
-            try:
-                import jax
-                _USE_HOST_HASH = jax.default_backend() == "cpu"
-            except Exception:
-                _USE_HOST_HASH = True
+        import jax
+        _USE_HOST_HASH = jax.default_backend() == "cpu"
     return _USE_HOST_HASH
+
+
 from .core import Types, get_types
 from .cow import CowColumn
 

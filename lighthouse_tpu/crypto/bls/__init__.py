@@ -49,6 +49,11 @@ class BlsBackend:
     def verify_signature_sets(self, sets: list[SignatureSet]) -> bool:
         raise NotImplementedError
 
+    def precompile(self) -> list:
+        """Compile the backend's device programs before the first batch
+        (a node calls this at start-up); host backends have none."""
+        return []
+
     def aggregate_signatures(self, sigs: list) -> bytes:
         raise NotImplementedError
 

@@ -15,9 +15,7 @@ verify_signature_sets :37-119).
 from __future__ import annotations
 
 import ctypes as C
-import pathlib
 import secrets
-import subprocess
 import time
 
 from . import BlsBackend, SignatureSet
@@ -27,12 +25,8 @@ _RAND_BITS = 64
 
 
 def _load_lib():
-    root = pathlib.Path(__file__).resolve().parents[3]
-    so = root / "native" / "libbls12381.so"
-    if not so.exists():
-        subprocess.run(["sh", str(root / "native" / "build.sh")],
-                       check=True, capture_output=True)
-    lib = C.CDLL(str(so))
+    from ...utils.native_build import library
+    lib = C.CDLL(str(library("bls12381")))
     u32p, u64p = C.POINTER(C.c_uint32), C.POINTER(C.c_uint64)
     lib.bls_selftest.restype = C.c_int
     lib.bls_sk_to_pk.argtypes = [C.c_char_p, C.c_char_p]
@@ -56,16 +50,14 @@ def _load_lib():
     lib.bls_aggregate_pks.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p]
     lib.bls_validate_pubkey.restype = C.c_int
     lib.bls_validate_pubkey.argtypes = [C.c_char_p]
-    try:  # KZG surface (crypto/kzg.py host acceleration)
-        lib.kzg_g1_msm.restype = C.c_int
-        lib.kzg_g1_msm.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p,
-                                   C.c_char_p]
-        lib.kzg_pairing_check.restype = C.c_int
-        lib.kzg_pairing_check.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p]
-        lib.kzg_g1_mul.restype = C.c_int
-        lib.kzg_g1_mul.argtypes = [C.c_char_p, C.c_char_p, C.c_char_p]
-    except AttributeError:
-        pass  # stale .so predating the KZG exports; kzg.py falls back
+    # KZG surface (crypto/kzg.py host acceleration)
+    lib.kzg_g1_msm.restype = C.c_int
+    lib.kzg_g1_msm.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p,
+                               C.c_char_p]
+    lib.kzg_pairing_check.restype = C.c_int
+    lib.kzg_pairing_check.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p]
+    lib.kzg_g1_mul.restype = C.c_int
+    lib.kzg_g1_mul.argtypes = [C.c_char_p, C.c_char_p, C.c_char_p]
     rc = lib.bls_selftest()
     if rc != 0:
         raise RuntimeError(f"bls12_381 native selftest failed: {rc}")

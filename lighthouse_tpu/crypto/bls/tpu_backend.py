@@ -101,6 +101,9 @@ class _PadCache:
         u0, u1 = k.hash_to_field_host([b""], DST_POP)
         self.u0 = u0
         self.u1 = u1
+        nx, ny = G1_GENERATOR.neg().to_affine()
+        self.neg_g_x = k.fp_encode([int(nx)])
+        self.neg_g_y = k.fp_encode([int(ny)])
 
     def tile(self, arr: np.ndarray, pad: int) -> np.ndarray:
         return np.broadcast_to(arr, (pad,) + arr.shape[1:])
@@ -208,12 +211,130 @@ def host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
         "u0": u0, "u1": u1, "starts": starts, "ends": ends, "mask": mask,
         "pk_rands": [rands[i] for i in order] + [0] * pad,
         "sig_rands": list(rands) + [0] * pad,
+        "neg_g_x": _PAD.neg_g_x, "neg_g_y": _PAD.neg_g_y,
         "n_groups": n_groups, "msg_lanes": msg_lanes,
     }
 
 
+#: threads compiling stage programs at start-up: 6 peaked at 19.9 GB of
+#: host memory compiling both lane shapes for a v5e (PR 21)
+COMPILE_THREADS = 6
+
+
+def device_checks(prep: dict, lanes: int):
+    """The device half of one chunk, prepared by :func:`host_prepare`:
+    yields, in order, the signatures' on-curve flags, their subgroup
+    flags and the batch pairing verdict.  A generator, so
+    ``_verify_chunk`` stops at the first failed check and
+    ``TpuBackend.precompile`` traces all three to find the programs.
+
+    SAME-MESSAGE AGGREGATION (PERF_MODEL.md §3.1): sets sharing a
+    message are folded into one pairing pair via
+    Σᵢ rᵢ·e(Pᵢ, H(m)) = e(Σᵢ rᵢPᵢ, H(m)) — a 10k gossip attestation
+    batch has ~128 distinct AttestationData messages, so hashing and
+    the Miller loop (70% of per-lane cost) run at the SMALL static
+    shape when the distinct messages fit."""
+    import jax.numpy as jnp
+
+    from ...ops import bls12_381 as k
+    from ...ops import bigint as bi
+
+    # device: signature decompression + subgroup check (generator
+    # padding keeps both checks uniformly True on padded lanes)
+    sig_x = jnp.asarray(prep["sig_x"])
+    sig_y, on_curve = k.g2_decompress_batch(sig_x, prep["flags"])
+    yield on_curve
+    one2 = jnp.asarray(np.broadcast_to(k.FP2_ONE, (lanes, 2, bi.NLIMBS)))
+    yield k.g2_in_subgroup_batch(sig_x, sig_y, one2)
+
+    # device: hash unique messages to G2 (host did expand_message_xmd)
+    mx, my, mz = k.hash_to_g2_batch_from_u(prep["u0"], prep["u1"])
+    msg_x, msg_y = k.jacobian_to_affine_fp2(mx, my, mz)
+
+    one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
+
+    # RLC scaling (padded lanes scale to infinity)
+    spx, spy, spz = k.g1_scalar_mul_jit(
+        prep["pk_x"], prep["pk_y"], one1,
+        k.scalars_to_bits(prep["pk_rands"], RAND_BITS))
+    ssx, ssy, ssz = k.g2_scalar_mul_jit(
+        sig_x, sig_y, one2,
+        k.scalars_to_bits(prep["sig_rands"], RAND_BITS))
+    # per-message pubkey sums (segmented log-depth reduction);
+    # group g's sum lands in lane g
+    gpx, gpy, gpz = k.g1_segment_sum(spx, spy, spz, prep["starts"],
+                                     prep["ends"])
+    # aggregate scaled signatures (scan reduction, 2 cached programs)
+    ax, ay, az = k.g2_sum(ssx, ssy, ssz)
+
+    # affine for the miller loop; non-group lanes come out as junk
+    # finite coordinates (z=0 inverts to 0) and are masked below
+    apx, apy = k.jacobian_to_affine_fp(gpx, gpy, gpz)
+    aax, aay = k.jacobian_to_affine_fp2(ax, ay, az)
+
+    # the aggregate signature pairs with -G1
+    px = jnp.concatenate([apx, jnp.asarray(prep["neg_g_x"])], axis=0)
+    py = jnp.concatenate([apy, jnp.asarray(prep["neg_g_y"])], axis=0)
+    qx = jnp.concatenate([msg_x, aax[None]], axis=0)
+    qy = jnp.concatenate([msg_y, aay[None]], axis=0)
+    yield k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"])
+
+
 class TpuBackend(PythonBackend):
     name = "tpu"
+
+    def precompile(self) -> list:
+        """Compile every stage program of both static lane shapes (with
+        the messages at the small shape: same-message gossip and
+        block-sized batches) in ``COMPILE_THREADS`` threads.  The
+        programs are found by tracing :func:`device_checks` on a
+        padded one-set chunk of each shape, so they are exactly those a
+        verify dispatches to; compiling them fills the executable cache
+        that dispatch reads.  One after another inside a cold node's
+        first batches they took ~15 minutes of TPU compiles (PR 21's
+        chip run); the compiler releases the GIL, so threads overlap
+        them.  Returns ``(name, jax.stages.Compiled)`` per program."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        import jax
+
+        from ...ops import bls12_381 as k
+        from ..bls12_381 import (
+            G1_GENERATOR, G2_GENERATOR, g1_compress, g2_compress,
+        )
+
+        jit_type = type(k.final_exponentiation)     # a jax.jit wrapper
+        jitted = {}
+        for f in vars(k).values():
+            if isinstance(f, jit_type):
+                if jitted.setdefault(f.__name__, f) is not f:
+                    raise RuntimeError(f"two stage programs are named "
+                                       f"{f.__name__!r}")
+        # one real set, the generators' — every other lane is padding
+        dummy = parse_sets(self, [SignatureSet(
+            g2_compress(G2_GENERATOR), [g1_compress(G1_GENERATOR)], b"")])
+        small, big = lane_options()
+        jobs = {}
+        for lanes in sorted({small, big}):
+            prep = host_prepare(*dummy, lanes, small)
+            arrays = {n: v for n, v in prep.items()
+                      if isinstance(v, np.ndarray)}
+            traced = jax.make_jaxpr(lambda a: list(
+                device_checks({**prep, **a}, lanes)))(arrays)
+            for eqn in traced.eqns:
+                if eqn.params.get("name") not in jitted:
+                    continue          # eager glue: tiny programs
+                args = tuple(jax.ShapeDtypeStruct(
+                    v.aval.shape, v.aval.dtype, weak_type=v.aval.weak_type)
+                    for v in eqn.invars)
+                name = eqn.params["name"]
+                key = (name, tuple((a.shape, a.dtype) for a in args))
+                jobs[key] = (name, jitted[name], args)
+        with ThreadPoolExecutor(max_workers=COMPILE_THREADS) as pool:
+            futures = [(name, pool.submit(
+                lambda fn, args: fn.lower(*args).compile(), fn, args))
+                for name, fn, args in jobs.values()]
+            return [(name, f.result()) for name, f in futures]
 
     def verify_signature_sets(self, sets: list[SignatureSet]) -> bool:
         if not sets:
@@ -236,69 +357,13 @@ class TpuBackend(PythonBackend):
     def _verify_chunk(self, pks, sig_xs, sig_flags, msgs,
                       lanes: int) -> bool:
         """One fixed-shape device pass over m<=lanes real sets, padded to
-        `lanes` with cached generator lanes (scalar 0, output masked).
-
-        SAME-MESSAGE AGGREGATION (PERF_MODEL.md §3.1): sets sharing a
-        message are folded into one pairing pair via
-        Σᵢ rᵢ·e(Pᵢ, H(m)) = e(Σᵢ rᵢPᵢ, H(m)) — a 10k gossip attestation
-        batch has ~128 distinct AttestationData messages, so hashing and
-        the Miller loop (70% of per-lane cost) run at the SMALL static
-        shape when the distinct messages fit (host prep + segment layout
-        shared with the mesh-sharded verifier in `host_prepare`)."""
-        import jax.numpy as jnp
-
-        from ...ops import bls12_381 as k
-        from ...ops import bigint as bi
-        from ..bls12_381 import G1_GENERATOR
-
+        `lanes` with cached generator lanes (scalar 0, output masked);
+        host prep + segment layout shared with the mesh-sharded
+        verifier in `host_prepare`."""
         prep = host_prepare(pks, sig_xs, sig_flags, msgs, lanes,
                             lane_options()[0])
-
-        # device: signature decompression + subgroup check (generator
-        # padding keeps both checks uniformly True on padded lanes)
-        sig_x = jnp.asarray(prep["sig_x"])
-        sig_y, on_curve = k.g2_decompress_batch(sig_x, prep["flags"])
-        if not bool(np.asarray(on_curve).all()):
-            return False
-        one2 = jnp.asarray(np.broadcast_to(k.FP2_ONE, (lanes, 2, bi.NLIMBS)))
-        if not bool(np.asarray(
-                k.g2_in_subgroup_batch(sig_x, sig_y, one2)).all()):
-            return False
-
-        # device: hash unique messages to G2 (host did expand_message_xmd)
-        mx, my, mz = k.hash_to_g2_batch_from_u(prep["u0"], prep["u1"])
-        msg_x, msg_y = k.jacobian_to_affine_fp2(mx, my, mz)
-
-        one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
-
-        # RLC scaling (padded lanes scale to infinity)
-        spx, spy, spz = k.g1_scalar_mul_jit(
-            prep["pk_x"], prep["pk_y"], one1,
-            k.scalars_to_bits(prep["pk_rands"], RAND_BITS))
-        ssx, ssy, ssz = k.g2_scalar_mul_jit(
-            sig_x, sig_y, one2,
-            k.scalars_to_bits(prep["sig_rands"], RAND_BITS))
-        # per-message pubkey sums (segmented log-depth reduction);
-        # group g's sum lands in lane g
-        gpx, gpy, gpz = k.g1_segment_sum(spx, spy, spz, prep["starts"],
-                                         prep["ends"])
-        # aggregate scaled signatures (scan reduction, 2 cached programs)
-        ax, ay, az = k.g2_sum(ssx, ssy, ssz)
-
-        # affine for the miller loop; non-group lanes come out as junk
-        # finite coordinates (z=0 inverts to 0) and are masked below
-        apx, apy = k.jacobian_to_affine_fp(gpx, gpy, gpz)
-        aax, aay = k.jacobian_to_affine_fp2(ax, ay, az)
-
-        neg_g = G1_GENERATOR.neg().to_affine()
-        ngx, ngy = k.fp_encode([int(neg_g[0])]), k.fp_encode([int(neg_g[1])])
-
-        px = jnp.concatenate([apx, jnp.asarray(ngx)], axis=0)
-        py = jnp.concatenate([apy, jnp.asarray(ngy)], axis=0)
-        qx = jnp.concatenate([msg_x, aax[None]], axis=0)
-        qy = jnp.concatenate([msg_y, aay[None]], axis=0)
-        return bool(np.asarray(
-            k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"])))
+        return all(bool(np.asarray(ok).all())
+                   for ok in device_checks(prep, lanes))
 
 
 def _encode_g1_batch(k, points):

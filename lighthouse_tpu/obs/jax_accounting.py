@@ -14,7 +14,7 @@ Three entry points:
   increments.  Where ``_cache_size`` is unavailable it falls back to
   abstract-shape bookkeeping (a fresh ``(shape, dtype)`` signature counts
   as a compile).  Compile *seconds* come from ``jax.monitoring`` duration
-  events when that API exists, else from the first-call wall time.
+  events.
 - :func:`host_readback` is THE sanctioned device->host crossing for
   ``parallel/`` (the device-transfer lint rule rejects bare
   ``np.asarray`` on device values there): it counts the bytes into
@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import sys
 import threading
-import time
 
 _lock = threading.Lock()
 _counters = {
@@ -104,38 +103,33 @@ def _record_cache_event(hit: bool) -> None:
 
 def install_monitoring() -> bool:
     """Route jax.monitoring compile-duration + persistent-compile-cache
-    events into the catalog.  Idempotent; returns whether the listeners
-    are installed."""
+    events into the catalog.  Idempotent; returns True."""
     global _monitoring_installed
     if _monitoring_installed:
         return True
-    try:
-        import jax.monitoring as jm
-    except Exception:
-        return False
-    if not hasattr(jm, "register_event_duration_secs_listener"):
-        return False
+    import jax.monitoring as jm
 
     def _on_duration(event: str, duration: float, **kw) -> None:
-        if "compile" in event:
+        # tracing, lowering and backend compile; a persistent-cache hit
+        # reports its retrieval under /jax/compilation_cache/ instead
+        if event.startswith("/jax/core/compile/"):
             with _lock:
                 _counters["compile_seconds"] += duration
             md = _metrics()
             if md is not None:
                 md.count("jax_compile_seconds_total", duration)
 
-    jm.register_event_duration_secs_listener(_on_duration)
-    # the persistent compile cache announces itself through bare events:
-    # /jax/compilation_cache/cache_hits on a hit (compiler.py) and
-    # /jax/compilation_cache/cache_misses on a miss (compilation_cache.py)
-    if hasattr(jm, "register_event_listener"):
-        def _on_event(event: str, **kw) -> None:
-            if event.endswith("/compilation_cache/cache_hits"):
-                _record_cache_event(True)
-            elif event.endswith("/compilation_cache/cache_misses"):
-                _record_cache_event(False)
+    def _on_event(event: str, **kw) -> None:
+        # the persistent compile cache announces itself through bare
+        # events: cache_hits (compiler.py), cache_misses
+        # (compilation_cache.py)
+        if event == "/jax/compilation_cache/cache_hits":
+            _record_cache_event(True)
+        elif event == "/jax/compilation_cache/cache_misses":
+            _record_cache_event(False)
 
-        jm.register_event_listener(_on_event)
+    jm.register_event_duration_secs_listener(_on_duration)
+    jm.register_event_listener(_on_event)
     _monitoring_installed = True
     return True
 
@@ -159,9 +153,8 @@ class TrackedJit:
 
     ``fn._cache_size()`` growth across a call is authoritative (it counts
     exactly the lowered-and-compiled programs); the shape-signature set
-    is the fallback.  The first call observed to compile also feeds
-    ``jax_compile_seconds_total`` with its wall time unless
-    jax.monitoring already reports compile durations.
+    is the fallback.  Compile seconds come from the jax.monitoring
+    listener this installs.
     """
 
     def __init__(self, name: str, fn):
@@ -184,9 +177,7 @@ class TrackedJit:
         key = None
         if before is None:
             key = _abstract_key(args, kwargs)
-        t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
-        wall = time.perf_counter() - t0
         after = self._cache_size()
         if after is not None:
             compiled = after > (before or 0)
@@ -194,8 +185,8 @@ class TrackedJit:
             compiled = key not in self._keys
             self._keys.add(key)
         if compiled:
-            _record_compile(1, 0.0 if _monitoring_installed else wall,
-                            self.name)
+            # seconds arrive through the jax.monitoring listener
+            _record_compile(1, 0.0, self.name)
             md = _metrics()
             if md is not None and after is not None:
                 md.gauge("jax_jit_cache_entries", after)

@@ -2,11 +2,9 @@
 
 Each compiled XLA program's ``cost_analysis()`` (FLOPs, bytes accessed)
 plus a measured wall time yields achieved FLOP/s, arithmetic intensity
-(FLOPs/byte) and utilization-of-peak against a small per-platform peak
-table — which is what makes ``LHTPU_BIGINT_MXU`` mode selection a
-*measured* decision and makes "measured on the CPU fallback"
-structurally impossible to miss: every roofline record carries the
-platform it ran on and the peak it was scored against.
+(FLOPs/byte) and utilization-of-peak against a peak table keyed by
+``device_kind`` — every roofline record carries the platform it ran on
+and the peak it was scored against.
 
 :func:`track_roofline` is the wrapper the memoized ``jit(shard_map)``
 factories in ``parallel/`` build their programs with (graftlint's
@@ -40,30 +38,31 @@ from . import jax_accounting
 #: everything after runs unbarriered
 SAMPLE_CALLS = 3
 
-#: nominal per-platform peaks the utilization ratio is scored against.
-#: Sources: TPU v5e datasheet (197 TFLOP/s bf16 / 394 TOP/s int8,
-#: 819 GB/s HBM, 16 GiB); the CPU row is a deliberately generous
-#: several-core AVX2 envelope so a CPU-fallback run can never flatter
-#: its utilization number.  Keys are matched case-insensitively against
-#: the device kind first, then the backend platform.
+#: nominal peaks the utilization ratio is scored against, keyed by the
+#: exact ``device_kind`` JAX reports.  A kind missing here is an error,
+#: never scored against another row.
 PEAKS: dict[str, dict] = {
-    "v5e": {"flops_per_sec": 197e12, "mem_bytes_per_sec": 819e9,
-            "label": "TPU v5e (bf16 MXU, nominal)"},
-    "v5litepod": {"flops_per_sec": 197e12, "mem_bytes_per_sec": 819e9,
-                  "label": "TPU v5e (bf16 MXU, nominal)"},
-    "tpu": {"flops_per_sec": 197e12, "mem_bytes_per_sec": 819e9,
-            "label": "TPU (v5e table, nominal)"},
-    "cpu": {"flops_per_sec": 200e9, "mem_bytes_per_sec": 50e9,
-            "label": "CPU fallback (nominal AVX2 envelope)"},
+    "TPU v5 lite": {
+        "flops_per_sec": 197e12, "mem_bytes_per_sec": 819e9,
+        "label": "TPU v5e (bf16 MXU, nominal)",
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 819 GB/s HBM"},
+    "cpu": {
+        "flops_per_sec": 200e9, "mem_bytes_per_sec": 50e9,
+        "label": "CPU (nominal AVX2 envelope)",
+        "source": "deliberately generous several-core AVX2 envelope, so "
+                  "a CPU run never flatters its utilization"},
 }
 
 
-def peak_for(platform: str, device_kind: str = "") -> dict:
-    for key in (device_kind or "").lower(), (platform or "").lower():
-        for match, peak in PEAKS.items():
-            if match in key and key:
-                return dict(peak, match=match)
-    return dict(PEAKS["cpu"], match="cpu")
+def peak_for(device_kind: str) -> dict:
+    """The peak row for ``device_kind``; raises KeyError for a device
+    the table does not know."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak recorded for device kind "
+                       f"{device_kind!r}; add it to obs/roofline.PEAKS "
+                       f"with its source")
+    return dict(PEAKS[device_kind], match=device_kind)
 
 
 def _metrics():
@@ -121,7 +120,7 @@ class _Program:
             out["cost"] = "unavailable"
             return out
         out.update(self.cost)
-        peak = peak_for(self.platform, self.device_kind)
+        peak = peak_for(self.device_kind)
         out["peak"] = peak["label"]
         if self.timed_calls and self.timed_seconds > 0:
             per_call = self.timed_seconds / self.timed_calls
@@ -156,14 +155,13 @@ class RooflineJit:
             if devs:
                 prog.device_kind = str(getattr(devs[0], "device_kind",
                                                "?"))
-            t0 = time.perf_counter()
             compiled = self._fn.lower(*args, **kwargs).compile()
-            wall = time.perf_counter() - t0
             prog.compiled = compiled
             prog.cost = _normalize_cost(compiled.cost_analysis())
             # the AOT path bypasses TrackedJit's cache detection, so
-            # feed the compile counters directly — one program, once
-            jax_accounting._record_compile(1, wall, self.name)
+            # count the program here; its seconds reach the counters
+            # through the jax.monitoring listener
+            jax_accounting._record_compile(1, 0.0, self.name)
         except Exception:
             prog.compiled = None        # fall back to the plain jit path
             prog.cost = None

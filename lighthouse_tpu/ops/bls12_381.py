@@ -518,6 +518,11 @@ g2_dbl, g2_add, g2_scalar_mul, g2_scalar_mul_const = _make_point_ops(
     fp2_add, fp2_sub, fp2_mul, fp2_square, fp2_muln, fp2_neg,
     fp2_is_zero, _where_fp2, _fp2_products)
 
+# each group's own name, so its program is told apart from the other's
+# (TpuBackend.precompile finds stage programs by name)
+g1_scalar_mul.__name__ = g1_scalar_mul.__qualname__ = "g1_scalar_mul"
+g2_scalar_mul.__name__ = g2_scalar_mul.__qualname__ = "g2_scalar_mul"
+
 # jitted entry points for the eager host pipeline (scan bodies compile
 # once; unjitted they dispatch op-by-op)
 g1_scalar_mul_jit = jax.jit(g1_scalar_mul)
@@ -526,30 +531,36 @@ g2_scalar_mul_jit = jax.jit(g2_scalar_mul)
 
 @jax.jit
 def g1_segment_sum(x, y, z, starts, ends):
-    """Per-segment Jacobian G1 sums in one log-depth pass.
+    """Per-segment Jacobian G1 sums in log-depth steps.
 
     Lanes are host-sorted so segments are contiguous; ``starts`` is 1 at
     each segment's first lane, ``ends[g]`` is the LAST lane index of
-    segment g (arbitrary for padding groups).  Implemented as a segmented
-    inclusive `associative_scan` (combine resets at boundaries — the
-    standard segmented-reduction operator, which stays associative), then
-    a gather at the segment ends.  This is what makes same-message
-    aggregation cheap: Σᵢ rᵢ·e(Pᵢ, H(m)) = e(Σᵢ rᵢPᵢ, H(m)), so a 10k
-    attestation batch with ~128 distinct messages needs ~128 Miller
-    pairs, not 10k (PERF_MODEL.md §3.1)."""
-    f = jnp.asarray(starts, dtype=jnp.int32)
+    segment g (arbitrary for padding groups).  A segmented inclusive
+    Hillis-Steele scan: step d adds the partial sum 2**d lanes back
+    unless a segment starts in between (the standard segmented-reduction
+    operator, which stays associative), then a gather at the segment
+    ends.  The steps run in one ``fori_loop``, so the G1 addition
+    compiles once (an unrolled ``associative_scan`` compiled 2*log2(n)
+    copies of it: 200 s for the TPU at 128 lanes).  This is what makes
+    same-message aggregation cheap: Σᵢ rᵢ·e(Pᵢ, H(m)) = e(Σᵢ rᵢPᵢ, H(m)),
+    so a 10k attestation batch with ~128 distinct messages needs ~128
+    Miller pairs, not 10k (PERF_MODEL.md §3.1)."""
+    n = x.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
 
-    def combine(a, b):
-        ax, ay, az, af = a
-        bx, by, bz, bf = b
-        sx, sy, sz = g1_add(ax, ay, az, bx, by, bz)
-        keep = bf.astype(bool)
-        return (jnp.where(keep[..., None], bx, sx),
-                jnp.where(keep[..., None], by, sy),
-                jnp.where(keep[..., None], bz, sz),
-                af | bf)
+    def step(d, carry):
+        cx, cy, cz, f = carry
+        src = lane - jnp.left_shift(jnp.int32(1), d)
+        has = src >= 0
+        src = jnp.maximum(src, 0)
+        sx, sy, sz = g1_add(cx[src], cy[src], cz[src], cx, cy, cz)
+        take = (has & ~f)[:, None]
+        return (jnp.where(take, sx, cx), jnp.where(take, sy, cy),
+                jnp.where(take, sz, cz), f | (has & f[src]))
 
-    ox, oy, oz, _ = jax.lax.associative_scan(combine, (x, y, z, f), axis=0)
+    f = jnp.asarray(starts, dtype=jnp.int32).astype(bool)
+    ox, oy, oz, _ = jax.lax.fori_loop(0, max(1, (n - 1).bit_length()),
+                                      step, (x, y, z, f))
     ends = jnp.asarray(ends, dtype=jnp.int32)
     return ox[ends], oy[ends], oz[ends]
 

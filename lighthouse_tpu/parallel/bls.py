@@ -17,10 +17,7 @@ import functools
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                              # jax >= 0.4.35 exports it at top level
-    from jax import shard_map
-except ImportError:               # older jax: experimental location
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..obs import device
 from ..obs.jax_accounting import host_readback

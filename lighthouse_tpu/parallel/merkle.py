@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                              # jax >= 0.4.35 exports it at top level
-    from jax import shard_map
-except ImportError:               # older jax: experimental location
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..obs import device
 from ..obs.roofline import track_roofline

@@ -9,9 +9,7 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
-from pathlib import Path
 
 
 class StoreError(Exception):
@@ -130,15 +128,12 @@ _LIB_CACHE: dict[str, ctypes.CDLL] = {}
 
 
 def _load_native() -> ctypes.CDLL:
-    root = Path(__file__).resolve().parents[2]
-    so = root / "native" / "libkvstore.so"
+    from ..utils.native_build import library
+    so = library("kvstore")
     key = str(so)
     if key in _LIB_CACHE:
         return _LIB_CACHE[key]
-    if not so.exists():
-        build = root / "native" / "build.sh"
-        subprocess.run(["sh", str(build)], check=True, capture_output=True)
-    lib = ctypes.CDLL(str(so))
+    lib = ctypes.CDLL(key)
     lib.kv_open.restype = ctypes.c_void_p
     lib.kv_open.argtypes = [ctypes.c_char_p]
     lib.kv_close.argtypes = [ctypes.c_void_p]

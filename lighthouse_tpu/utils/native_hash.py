@@ -1,14 +1,15 @@
 """ctypes binding for the C++ batch SHA-256 (native/sha256_host.cpp).
 
 The host-side analog of `ethereum_hashing`: one FFI crossing per merkle
-level. Falls back cleanly when the library is missing (pure hashlib paths
-keep working).
+level. Where the library cannot be built, `hash64_batch` and
+`merkle_root_pow2` hash with hashlib (slowly) and the other helpers
+report it, so every host-hashed column still works.
 """
 from __future__ import annotations
 
 import ctypes
-import subprocess
-from pathlib import Path
+import hashlib
+import warnings
 
 import numpy as np
 
@@ -17,47 +18,37 @@ _checked = False
 
 
 def get_lib():
+    """The SHA-256 host library, built from the committed source; None
+    only where it cannot be built here (said once, as a warning)."""
     global _lib, _checked
     if _checked:
         return _lib
     _checked = True
-    root = Path(__file__).resolve().parents[2]
-    so = root / "native" / "libsha256host.so"
-    cpp = root / "native" / "sha256_host.cpp"
+    from .native_build import BuildError, library
     try:
-        # rebuild when missing OR stale (the source has grown entry points
-        # since the .so was compiled; dlopen caches by path, so this must
-        # happen before the first CDLL of the process)
-        if not so.exists() or (cpp.exists()
-                               and so.stat().st_mtime < cpp.stat().st_mtime):
-            subprocess.run(["sh", str(root / "native" / "build.sh")],
-                           check=True, capture_output=True)
-        lib = ctypes.CDLL(str(so))
-        lib.sha256_have_shani.restype = ctypes.c_int
-        lib.sha256_hash64_batch.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
-                                            ctypes.c_uint64]
-        lib.sha256_merkle_root.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
-                                           ctypes.c_char_p, ctypes.c_char_p]
-        lib.sha256_oneshot.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
-                                       ctypes.c_char_p]
-        try:   # threaded entry points (absent in a stale .so)
-            lib.sha256_merkle_root_mt.argtypes = [
-                ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
-                ctypes.c_char_p, ctypes.c_uint32]
-            lib.sha256_hash64_batch_mt.argtypes = [
-                ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
-                ctypes.c_uint32]
-        except AttributeError:
-            pass
-        try:   # short-message batch (absent in a stale .so)
-            lib.sha256_short_batch.argtypes = [
-                ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
-                ctypes.c_uint64]
-        except AttributeError:
-            pass
-        _lib = lib
-    except Exception:
-        _lib = None
+        so = library("sha256host")
+    except BuildError as exc:
+        warnings.warn(f"native SHA-256 unavailable, host hashing falls "
+                      f"back to hashlib (slow): {exc}")
+        return None
+    lib = ctypes.CDLL(str(so))
+    lib.sha256_have_shani.restype = ctypes.c_int
+    lib.sha256_hash64_batch.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
+                                        ctypes.c_uint64]
+    lib.sha256_merkle_root.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
+                                       ctypes.c_char_p, ctypes.c_char_p]
+    lib.sha256_oneshot.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
+                                   ctypes.c_char_p]
+    lib.sha256_merkle_root_mt.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
+        ctypes.c_char_p, ctypes.c_uint32]
+    lib.sha256_hash64_batch_mt.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
+        ctypes.c_uint32]
+    lib.sha256_short_batch.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
+        ctypes.c_uint64]
+    _lib = lib
     return _lib
 
 
@@ -70,6 +61,10 @@ def hash64_batch(data: bytes) -> bytes:
     """n*64 bytes in -> n*32 digests out."""
     lib = get_lib()
     n = len(data) // 64
+    if lib is None:
+        view = memoryview(data)
+        return b"".join(hashlib.sha256(view[i:i + 64]).digest()
+                        for i in range(0, n * 64, 64))
     out = ctypes.create_string_buffer(n * 32)
     lib.sha256_hash64_batch(data, out, n)
     return out.raw
@@ -77,10 +72,10 @@ def hash64_batch(data: bytes) -> bytes:
 
 def hash_short_batch(data: bytes, msg_len: int) -> bytes | None:
     """n independent msg_len-byte messages (msg_len <= 55, one padded
-    block each) -> n*32 digests; None when the library or the symbol is
-    unavailable (callers keep a hashlib loop as the fallback)."""
+    block each) -> n*32 digests; None when the library is unavailable or
+    the message is too long (callers keep a hashlib loop)."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "sha256_short_batch") or msg_len > 55:
+    if lib is None or msg_len > 55:
         return None
     n = len(data) // msg_len
     out = ctypes.create_string_buffer(n * 32)
@@ -90,13 +85,18 @@ def hash_short_batch(data: bytes, msg_len: int) -> bytes | None:
 
 def merkle_root_pow2(leaves: bytes, threads: int | None = None) -> bytes:
     """Dense merkle root of a power-of-two number of 32-byte leaves
-    (threaded across cores for big trees when the .so supports it)."""
+    (threaded across cores for big trees)."""
     import os
     lib = get_lib()
     n = len(leaves) // 32
+    if lib is None:
+        level = bytes(leaves)
+        while len(level) > 32:
+            level = hash64_batch(level)
+        return level
     root = ctypes.create_string_buffer(32)
     t = threads if threads is not None else (os.cpu_count() or 1)
-    if t > 1 and hasattr(lib, "sha256_merkle_root_mt"):
+    if t > 1:
         # the threaded variant ping-pongs levels across two scratch halves
         scratch = ctypes.create_string_buffer(max(64, n * 32))
         lib.sha256_merkle_root_mt(leaves, n, root, scratch, t)

@@ -68,6 +68,36 @@ def test_g1_scalar_mul_matches():
         assert k.fp_decode(ay[i])[0] == int(want[1])
 
 
+def test_g1_segment_sum_matches():
+    # contiguous segments of lengths 1, 4, 7, 2, 10 and 13 (37 lanes, a
+    # non-power of two), one lane at infinity, two padding groups
+    seglens = [1, 4, 7, 2, 10, 13]
+    pts = [G1_GENERATOR.mul(int(s))
+           for s in rng.integers(1, 2**40, size=sum(seglens))]
+    x, y = _encode_g1(pts)
+    z = np.array(np.broadcast_to(k.FP_ONE, (len(pts), bi.NLIMBS)))
+    z[5] = 0
+    starts = np.zeros(len(pts), np.int32)
+    ends = np.zeros(len(seglens) + 2, np.int32)
+    pos = 0
+    for g, n in enumerate(seglens):
+        starts[pos] = 1
+        ends[g] = pos + n - 1
+        pos += n
+    sx, sy, sz = k.g1_segment_sum(x, y, z, starts, ends)
+    ax, ay = k.jacobian_to_affine_fp(sx, sy, sz)
+    pos = 0
+    for g, n in enumerate(seglens):
+        want = None
+        for i in range(pos, pos + n):
+            if i != 5:
+                want = pts[i] if want is None else want.add(pts[i])
+        pos += n
+        wx, wy = want.to_affine()
+        assert k.fp_decode(ax[g])[0] == int(wx)
+        assert k.fp_decode(ay[g])[0] == int(wy)
+
+
 def test_g2_add_dbl_matches():
     p2 = G2_GENERATOR.double()
     p3 = p2.add(G2_GENERATOR)

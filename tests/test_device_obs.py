@@ -113,12 +113,15 @@ def test_roofline_falls_back_when_aot_lowering_fails():
     assert rec["cost"] == "unavailable"
 
 
-def test_peak_table_matches_device_kind_before_platform():
-    peak = roofline.peak_for("tpu", "TPU v5e")
-    assert peak["match"] == "v5e"
-    assert roofline.peak_for("cpu", "")["match"] == "cpu"
-    # unknown platforms score against the CPU envelope, never flatter
-    assert roofline.peak_for("weird", "")["match"] == "cpu"
+def test_peak_table_is_keyed_by_exact_device_kind():
+    peak = roofline.peak_for("TPU v5 lite")
+    assert peak["match"] == "TPU v5 lite"
+    assert peak["flops_per_sec"] == 197e12 and peak["source"]
+    assert roofline.peak_for("cpu")["match"] == "cpu"
+    # an unknown kind is an error, never scored as a CPU or as a v5e
+    for kind in ("TPU v5e", "TPU v4", "tpu", ""):
+        with pytest.raises(KeyError):
+            roofline.peak_for(kind)
 
 
 # -- span watermarks ----------------------------------------------------------

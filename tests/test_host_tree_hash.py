@@ -117,3 +117,29 @@ def test_host_tree_primitive_and_threaded_root():
     leaves = rng.integers(0, 256, size=(1 << 15) * 32, dtype=np.uint8)
     assert nh.merkle_root_pow2(bytes(leaves), threads=4) == \
         nh.merkle_root_pow2(bytes(leaves), threads=1)
+
+
+def test_host_hashing_without_the_library(monkeypatch):
+    """Where the native library cannot be built, the host-hashed columns
+    hash with hashlib and give the library's roots, incremental and
+    copy-on-write paths included."""
+    rng = np.random.default_rng(7)
+    vr = _registry(300, rng)
+    vals = rng.integers(0, 2**40, size=513, dtype=np.uint64)
+    st._USE_HOST_HASH = True
+
+    def roots(vr):
+        root = vr.hash_tree_root(LIMIT)
+        clone = vr.copy()
+        clone.set_field(7, "exit_epoch", 42)
+        clone._root_cache = None
+        bc = BalancesColumn(vals.copy())
+        bal = bc.hash_tree_root(LIMIT)
+        bc.set(512, 1)
+        return root, clone.hash_tree_root(LIMIT), bal, \
+            bc.hash_tree_root(LIMIT)
+
+    want = roots(vr)
+    monkeypatch.setattr(nh, "get_lib", lambda: None)
+    fresh = _registry(300, np.random.default_rng(7))
+    assert roots(fresh) == want

@@ -391,10 +391,10 @@ def test_proposer_index_sparse_path_matches_dense(monkeypatch):
 def test_epoch_processing_64k_smoke():
     """64k-validator mainnet-preset epoch: the vectorized envelope paths
     run end-to-end on a large SoA state and rotate participation."""
-    import bench
     from lighthouse_tpu.state_transition import per_epoch_processing
+    from lighthouse_tpu.testing.mainnet_state import build_beacon_state
     slot = 100_000 * 32 + 2
-    state = bench.build_beacon_state(64 * 1024, slot)
+    state = build_beacon_state(64 * 1024, slot)
     state.slot = (slot // 32) * 32 + 31
     before_cur = state.current_epoch_participation.copy()
     per_epoch_processing(state)
