@@ -116,10 +116,6 @@ def bench_bls():
             assert tpu.verify_signature_sets(sets)
         times.append(time.perf_counter() - t0)
     secs = min(times)
-    # bls_device_pairing_seconds is catalog-declared but only observable
-    # end-to-end here (EXTERNALLY_FED): record the per-batch device time
-    import lighthouse_tpu.api.metrics_defs as _md
-    _md.observe("bls_device_pairing_seconds", secs)
     return n / secs, n
 
 

@@ -27,6 +27,15 @@ CATALOG: dict[str, tuple[str, str]] = {
         ("hist", "fork_choice.on_block latency"),
     "beacon_block_processing_db_write_seconds":
         ("hist", "Block + state persistence latency"),
+    "beacon_block_processing_pre_state_seconds":
+        ("hist", "Parent state lookup, copy and slot advance for import"),
+    "beacon_block_processing_signature_sets_seconds":
+        ("hist", "Building a block's signature sets (not verifying them)"),
+    "beacon_block_processing_post_import_seconds":
+        ("hist", "After the store write: validator monitor, caches, "
+                 "events, reprocess wake, light client"),
+    "beacon_block_processing_head_update_seconds":
+        ("hist", "recompute_head after a block import"),
     "beacon_block_imported_total":
         ("counter", "Blocks imported"),
     "beacon_block_production_seconds":
@@ -169,8 +178,26 @@ CATALOG: dict[str, tuple[str, str]] = {
     "store_fsck_errors_total":
         ("counter", "Consistency errors reported by store fsck"),
     # -- crypto hot spots -------------------------------------------------
-    "bls_batch_verify_sigs": ("hist", "Signatures per device batch"),
-    "bls_device_pairing_seconds": ("hist", "Device pairing-check latency"),
+    "bls_parse_seconds":
+        ("hist", "BLS batch host parse: pubkey aggregation, signature "
+                 "range checks"),
+    "bls_prepare_seconds":
+        ("hist", "BLS batch host preparation: grouping, RLC scalars, "
+                 "encoding, hash-to-field"),
+    "bls_scalars_seconds":
+        ("hist", "RLC scalars to bit matrices on the host"),
+    "bls_device_decompress_seconds":
+        ("hist", "Device occupancy of signature decompression"),
+    "bls_device_subgroup_seconds":
+        ("hist", "Device occupancy of the signature subgroup check"),
+    "bls_device_hash_to_g2_seconds":
+        ("hist", "Device occupancy of hash-to-G2"),
+    "bls_device_rlc_seconds":
+        ("hist", "Device occupancy of the RLC scalar multiplications and "
+                 "sums"),
+    "bls_device_pairing_seconds":
+        ("hist", "Device occupancy of the pairing check (Miller loop, "
+                 "final exponentiation)"),
     "tree_hash_root_seconds": ("hist", "BeaconState tree_hash latency"),
     # -- CoW state columns (containers/cow.py) ----------------------------
     "state_copy_seconds":
@@ -295,12 +322,7 @@ CATALOG: dict[str, tuple[str, str]] = {
 #: Histograms declared for dashboard parity but fed outside the node
 #: process (tier-1's catalog-completeness test accepts these).  Keyed by
 #: name with the feeding agent as the justification.
-EXTERNALLY_FED: dict[str, str] = {
-    "bls_device_pairing_seconds":
-        "observed by the TPU bench harness (bench.py bls mode), which is "
-        "the only place the device pairing check runs end-to-end with a "
-        "meaningful batch on real hardware",
-}
+EXTERNALLY_FED: dict[str, str] = {}
 
 
 def register_catalog() -> int:

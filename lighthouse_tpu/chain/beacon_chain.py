@@ -12,6 +12,7 @@ the execution layer's blocking I/O — guards are never exposed.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -471,74 +472,83 @@ class BeaconChain:
             "observed_slot": self.slot()}
         self.block_times_cache.on_imported(block_root, block.slot)
         M.count("beacon_block_imported_total")
-        with self._lock:
-            with tracing.span("fork_choice"):
-                self.fork_choice.on_block(current_slot, block, block_root,
-                                          state, block_delay_seconds=delay,
-                                          execution_status=status)
-                # on-block attestations feed LMD votes (is_from_block)
-                indexed_atts = []
-                for att in block.body.attestations:
-                    try:
-                        indexed = get_indexed_attestation(state, att)
-                        indexed_atts.append(indexed)
-                        self.fork_choice.on_attestation(
-                            current_slot, indexed, is_from_block=True)
-                    except Exception as e:  # best-effort
-                        import logging
+        with contextlib.ExitStack() as after:
+            with self._lock:
+                with tracing.span("fork_choice"):
+                    self.fork_choice.on_block(current_slot, block, block_root,
+                                              state, block_delay_seconds=delay,
+                                              execution_status=status)
+                    # on-block attestations feed LMD votes (is_from_block)
+                    indexed_atts = []
+                    for att in block.body.attestations:
+                        try:
+                            indexed = get_indexed_attestation(state, att)
+                            indexed_atts.append(indexed)
+                            self.fork_choice.on_attestation(
+                                current_slot, indexed, is_from_block=True)
+                        except Exception as e:  # best-effort
+                            import logging
 
-                        from ..fork_choice import ForkChoiceError
-                        # ForkChoiceError here is routine during fork-branch
-                        # imports (the block's attestations can reference
-                        # ancestors the store hasn't seen yet); anything
-                        # else is worth a warning.
-                        lvl = (logging.DEBUG if isinstance(e, ForkChoiceError)
-                               else logging.WARNING)
-                        logging.getLogger("lighthouse_tpu.chain").log(
-                            lvl, "on-block attestation skipped in fork "
-                            "choice: %r", e)
-                for slashing in block.body.attester_slashings:
-                    self.fork_choice.on_attester_slashing(
-                        slashing.attestation_1)
-            self.validator_monitor.on_block_imported(block, indexed_atts,
-                                                     block_root=block_root)
-            if state.current_epoch() > self._monitored_epoch:
-                self._monitored_epoch = state.current_epoch()
-                self.validator_monitor.on_epoch_transition(
-                    self._monitored_epoch - 1, state)
-            self.validator_monitor.note_state(state)
-            with tracing.span("db_write"):
-                # block + state land as ONE log record: a crash at either
-                # side of the batch leaves the store before-or-after, never
-                # a block whose post-state is missing
-                crashpoint("block_import:before_batch")
-                self.store.do_atomically(
-                    [StoreOp.put_block(block_root, ep.signed_block),
-                     StoreOp.put_state(block.state_root, state)],
-                    fsync=False)
-                crashpoint("block_import:after_state_write")
-                self._cache_snapshot(block_root, state)
-            try:
-                # serve attestations for this block state-free from now on
-                # (early_attester_cache.rs:1-30, attester_cache.rs:1-60)
-                self.early_attester_cache.add(self, block_root, block, state)
-                self.attester_cache.cache_state(self, state)
-                self.eth1_finalization_cache.insert(state, block_root)
-            except Exception:               # pragma: no cover - advisory
-                pass
-        self.events.emit("block", {"slot": block.slot,
-                                   "block_root": block_root})
-        if self.processor is not None:
-            # wake attestations parked on this root
-            self.processor.reprocess.on_block_imported(block_root)
-        if self.config.enable_light_client_server:
-            try:
-                self.light_client_cache.on_head_update(ep.signed_block, state)
-            except Exception:
-                import logging
-                logging.getLogger("lighthouse_tpu.chain").exception(
-                    "light client cache update failed")
-        self.recompute_head()
+                            from ..fork_choice import ForkChoiceError
+                            # ForkChoiceError here is routine during
+                            # fork-branch imports (the block's attestations
+                            # can reference ancestors the store hasn't seen
+                            # yet); anything else is worth a warning.
+                            lvl = (logging.DEBUG
+                                   if isinstance(e, ForkChoiceError)
+                                   else logging.WARNING)
+                            logging.getLogger("lighthouse_tpu.chain").log(
+                                lvl, "on-block attestation skipped in fork "
+                                "choice: %r", e)
+                    for slashing in block.body.attester_slashings:
+                        self.fork_choice.on_attester_slashing(
+                            slashing.attestation_1)
+                with tracing.span("db_write"):
+                    # block + state land as ONE log record: a crash at
+                    # either side of the batch leaves the store
+                    # before-or-after, never a block whose post-state is
+                    # missing
+                    crashpoint("block_import:before_batch")
+                    self.store.do_atomically(
+                        [StoreOp.put_block(block_root, ep.signed_block),
+                         StoreOp.put_state(block.state_root, state)],
+                        fsync=False)
+                    crashpoint("block_import:after_state_write")
+                    self._cache_snapshot(block_root, state)
+                # post_import: the monitor, caches, events and light
+                # client, after the store write and before the head update
+                after.enter_context(tracing.span("post_import"))
+                self.validator_monitor.on_block_imported(block, indexed_atts,
+                                                         block_root=block_root)
+                if state.current_epoch() > self._monitored_epoch:
+                    self._monitored_epoch = state.current_epoch()
+                    self.validator_monitor.on_epoch_transition(
+                        self._monitored_epoch - 1, state)
+                self.validator_monitor.note_state(state)
+                try:
+                    # serve attestations for this block state-free from now
+                    # on (early_attester_cache.rs:1-30, attester_cache.rs:1-60)
+                    self.early_attester_cache.add(self, block_root, block,
+                                                  state)
+                    self.attester_cache.cache_state(self, state)
+                    self.eth1_finalization_cache.insert(state, block_root)
+                except Exception:               # pragma: no cover - advisory
+                    pass
+            self.events.emit("block", {"slot": block.slot,
+                                       "block_root": block_root})
+            if self.processor is not None:
+                # wake attestations parked on this root
+                self.processor.reprocess.on_block_imported(block_root)
+            if self.config.enable_light_client_server:
+                try:
+                    self.light_client_cache.on_head_update(ep.signed_block,
+                                                           state)
+                except Exception:
+                    import logging
+                    logging.getLogger("lighthouse_tpu.chain").exception(
+                        "light client cache update failed")
+        with tracing.span("head_update"):
+            self.recompute_head()
         return block_root
 
     def replay_engine(self):

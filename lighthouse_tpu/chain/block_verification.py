@@ -178,11 +178,13 @@ def into_signature_verified(chain, signed_block, block_root: bytes,
     """Batch-verify every signature in the block
     (BlockSignatureVerifier::verify_entire_block via block_verification.rs:1286)."""
     block = signed_block.message
-    state = chain.state_for_block_import(block.parent_root, block.slot)
-    verifier = BlockSignatureVerifier(state)
-    verifier.include_entire_block(signed_block, block_root)
-    if proposal_already_verified:
-        verifier.sets = verifier.sets[1:]  # proposal set is always first
+    with tracing.span("pre_state"):
+        state = chain.state_for_block_import(block.parent_root, block.slot)
+    with tracing.span("signature_sets"):
+        verifier = BlockSignatureVerifier(state)
+        verifier.include_entire_block(signed_block, block_root)
+        if proposal_already_verified:
+            verifier.sets = verifier.sets[1:]  # proposal set is always first
     if not verifier.verify():
         raise BlockError(INVALID_SIGNATURE, "block signature batch")
     return SignatureVerifiedBlock(signed_block, block_root, state)

@@ -290,7 +290,6 @@ def verify_signature_sets(sets: list[SignatureSet]) -> bool:
     m = sys.modules.get("lighthouse_tpu.api.metrics_defs")
     if m is not None:
         m.observe("beacon_batch_verify_signature_sets", len(sets))
-        m.observe("bls_batch_verify_sigs", len(sets))
     return out
 
 

@@ -39,6 +39,7 @@ import secrets
 
 import numpy as np
 
+from ...obs import tracing
 from . import BlsBackend, PythonBackend, SignatureSet
 
 RAND_BITS = 64
@@ -118,6 +119,11 @@ def parse_sets(backend, sets):
     signature x/flag extraction with range checks.  Returns
     (pks, sig_xs, flags, msgs) or None when any set is malformed (the
     batch must verify False, not raise)."""
+    with tracing.span("bls_parse"):
+        return _parse_sets(backend, sets)
+
+
+def _parse_sets(backend, sets):
     from ..bls12_381.fields import P as P_INT
     pks, sig_xs, flags, msgs = [], [], [], []
     try:
@@ -151,6 +157,11 @@ def host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
     grouping (segment layout for `g1_segment_sum`), RLC scalars, and the
     padded device input arrays (cached generator constants on padding
     lanes).  Returns a dict of arrays + layout."""
+    with tracing.span("bls_prepare"):
+        return _host_prepare(pks, sig_xs, sig_flags, msgs, lanes, small)
+
+
+def _host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
     import secrets
 
     from ...ops import bigint as bi
@@ -234,32 +245,49 @@ def device_checks(prep: dict, lanes: int):
     batch has ~128 distinct AttestationData messages, so hashing and
     the Miller loop (70% of per-lane cost) run at the SMALL static
     shape when the distinct messages fit."""
+    import contextlib
+
+    import jax
     import jax.numpy as jnp
 
     from ...ops import bls12_381 as k
     from ...ops import bigint as bi
 
+    # each stage is a device span; traced by make_jaxpr (precompile),
+    # the inputs are tracers and no span of either kind is recorded
+    live = not isinstance(prep["sig_x"], jax.core.Tracer)
+
+    def scalar_bits(rands):
+        # pure-Python host work, run while the device works
+        with (tracing.span("bls_scalars") if live
+              else contextlib.nullcontext()):
+            return k.scalars_to_bits(rands, RAND_BITS)
+
     # device: signature decompression + subgroup check (generator
     # padding keeps both checks uniformly True on padded lanes)
+    stage = tracing.device_span("bls_decompress")
     sig_x = jnp.asarray(prep["sig_x"])
-    sig_y, on_curve = k.g2_decompress_batch(sig_x, prep["flags"])
+    sig_y, on_curve = stage.watch(
+        k.g2_decompress_batch(sig_x, prep["flags"]))
     yield on_curve
+    stage = tracing.device_span("bls_subgroup")
     one2 = jnp.asarray(np.broadcast_to(k.FP2_ONE, (lanes, 2, bi.NLIMBS)))
-    yield k.g2_in_subgroup_batch(sig_x, sig_y, one2)
+    yield stage.watch(k.g2_in_subgroup_batch(sig_x, sig_y, one2))
 
     # device: hash unique messages to G2 (host did expand_message_xmd)
+    stage = tracing.device_span("bls_hash_to_g2")
     mx, my, mz = k.hash_to_g2_batch_from_u(prep["u0"], prep["u1"])
-    msg_x, msg_y = k.jacobian_to_affine_fp2(mx, my, mz)
+    msg_x, msg_y = stage.watch(k.jacobian_to_affine_fp2(mx, my, mz))
 
     one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
 
     # RLC scaling (padded lanes scale to infinity)
+    pk_bits = scalar_bits(prep["pk_rands"])
+    stage = tracing.device_span("bls_rlc")
     spx, spy, spz = k.g1_scalar_mul_jit(
-        prep["pk_x"], prep["pk_y"], one1,
-        k.scalars_to_bits(prep["pk_rands"], RAND_BITS))
+        prep["pk_x"], prep["pk_y"], one1, pk_bits)
     ssx, ssy, ssz = k.g2_scalar_mul_jit(
-        sig_x, sig_y, one2,
-        k.scalars_to_bits(prep["sig_rands"], RAND_BITS))
+        sig_x, sig_y, one2, scalar_bits(prep["sig_rands"]))
     # per-message pubkey sums (segmented log-depth reduction);
     # group g's sum lands in lane g
     gpx, gpy, gpz = k.g1_segment_sum(spx, spy, spz, prep["starts"],
@@ -273,11 +301,14 @@ def device_checks(prep: dict, lanes: int):
     aax, aay = k.jacobian_to_affine_fp2(ax, ay, az)
 
     # the aggregate signature pairs with -G1
-    px = jnp.concatenate([apx, jnp.asarray(prep["neg_g_x"])], axis=0)
-    py = jnp.concatenate([apy, jnp.asarray(prep["neg_g_y"])], axis=0)
-    qx = jnp.concatenate([msg_x, aax[None]], axis=0)
-    qy = jnp.concatenate([msg_y, aay[None]], axis=0)
-    yield k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"])
+    px, py, qx, qy = stage.watch((
+        jnp.concatenate([apx, jnp.asarray(prep["neg_g_x"])], axis=0),
+        jnp.concatenate([apy, jnp.asarray(prep["neg_g_y"])], axis=0),
+        jnp.concatenate([msg_x, aax[None]], axis=0),
+        jnp.concatenate([msg_y, aay[None]], axis=0)))
+    stage = tracing.device_span("bls_pairing")
+    yield stage.watch(
+        k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"]))
 
 
 class TpuBackend(PythonBackend):
@@ -362,8 +393,12 @@ class TpuBackend(PythonBackend):
         verifier in `host_prepare`."""
         prep = host_prepare(pks, sig_xs, sig_flags, msgs, lanes,
                             lane_options()[0])
-        return all(bool(np.asarray(ok).all())
-                   for ok in device_checks(prep, lanes))
+        try:
+            return all(bool(np.asarray(ok).all())
+                       for ok in device_checks(prep, lanes))
+        finally:
+            # the stages' outputs are read: their spans land at once
+            tracing.wait_device_spans()
 
 
 def _encode_g1_batch(k, points):

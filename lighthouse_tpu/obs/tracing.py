@@ -18,6 +18,13 @@ fed most of them.  This module is the single timing substrate:
   (:func:`set_slot_clock`), every trace root records the slot and the
   delay from slot start — the lateness signal the block-times cache and
   validator monitor read.
+- Host spans are on the profiler's clock too: once JAX is loaded, an
+  open span holds a ``jax.profiler.TraceAnnotation`` named
+  ``lighthouse_tpu:<kind>``, so a JAX profile of a running node shows
+  each span beside the device programs it dispatched.
+- :class:`device_span` records a device stage's occupancy without
+  blocking the host: a watcher thread waits for the stage's outputs and
+  pushes the span when they are ready.
 
 Deliberately stdlib-only and import-light: the ring is plain Python, the
 metrics feed goes through ``sys.modules`` (never imports the api package
@@ -29,8 +36,10 @@ function would run at trace time only.
 """
 from __future__ import annotations
 
+import atexit
 import itertools
 import os
+import queue
 import sys
 import threading
 import time
@@ -48,12 +57,26 @@ SPAN_KINDS: dict[str, str] = {
     "state_root": "beacon_block_processing_state_root_seconds",
     "fork_choice": "beacon_block_processing_fork_choice_seconds",
     "db_write": "beacon_block_processing_db_write_seconds",
+    "pre_state": "beacon_block_processing_pre_state_seconds",
+    "signature_sets": "beacon_block_processing_signature_sets_seconds",
+    "post_import": "beacon_block_processing_post_import_seconds",
+    "head_update": "beacon_block_processing_head_update_seconds",
     "block_production": "beacon_block_production_seconds",
     # attestation plane
     "attestation_verify": "beacon_attestation_processing_seconds",
     "aggregate_verify": "beacon_aggregate_processing_seconds",
     # crypto hot spots
     "bls_batch_verify": "beacon_batch_verify_seconds",
+    # inside the BLS backend's batch (crypto/bls/tpu_backend.py): host
+    # stages, then device stages recorded by device_span
+    "bls_parse": "bls_parse_seconds",
+    "bls_prepare": "bls_prepare_seconds",
+    "bls_scalars": "bls_scalars_seconds",
+    "bls_decompress": "bls_device_decompress_seconds",
+    "bls_subgroup": "bls_device_subgroup_seconds",
+    "bls_hash_to_g2": "bls_device_hash_to_g2_seconds",
+    "bls_rlc": "bls_device_rlc_seconds",
+    "bls_pairing": "bls_device_pairing_seconds",
     "tree_hash": "tree_hash_root_seconds",
     "kzg_verify": "kzg_blob_verification_seconds",
     # beacon processor + store + execution layer
@@ -84,6 +107,8 @@ SPAN_KINDS: dict[str, str] = {
 
 _RING_CAPACITY = 4096
 _PID = os.getpid()
+#: a host span's profiler annotation is named this prefix + its kind
+PROFILER_PREFIX = "lighthouse_tpu:"
 
 
 class Span:
@@ -164,6 +189,8 @@ class _Ctx(threading.local):
         #: capture()/attach); None = unscoped thread, whose *root* spans
         #: adopt every globally active scope (see capture_scope)
         self.scopes: frozenset | None = None
+        #: device spans this thread watched and has not waited for
+        self.device_pending: list[device_span] = []
 
 
 _ctx = _Ctx()
@@ -313,6 +340,41 @@ def _observe_metric(name: str, value: float) -> None:
         md.observe(name, value)
 
 
+def _open_context() -> tuple[str, str | None, frozenset]:
+    """(trace_id, parent_id, scopes) for a span opened now on this
+    thread: a child of the current span, else of the inherited context,
+    else a new trace root."""
+    parent = current_span()
+    if parent is not None:
+        return parent.trace_id, parent.span_id, parent.scopes
+    if _ctx.inherited is not None:
+        trace_id, parent_id = _ctx.inherited
+    else:
+        trace_id, parent_id = _new_id(), None
+    scopes = (_ctx.scopes if _ctx.scopes is not None
+              else _active_scope_snapshot())
+    return trace_id, parent_id, scopes
+
+
+def _record(s: Span) -> None:
+    """Push a finished span into the ring and its catalog histogram."""
+    _ring.push(s)
+    metric = SPAN_KINDS[s.kind]
+    if metric:
+        _observe_metric(metric, s.duration)
+
+
+def _profiler_annotation(kind: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for ``kind``, or None
+    while JAX is not loaded (this module never imports it)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(PROFILER_PREFIX + kind)
+    annotation.__enter__()
+    return annotation
+
+
 class span:
     """Context manager opening a child of the current span (or a new
     trace root).  ``kind`` must be a registered ``SPAN_KINDS`` key."""
@@ -323,19 +385,10 @@ class span:
         self.kind = kind
         self._attrs = attrs
         self._span: Span | None = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
-        parent = current_span()
-        if parent is not None:
-            trace_id, parent_id = parent.trace_id, parent.span_id
-            scopes = parent.scopes
-        else:
-            if _ctx.inherited is not None:
-                trace_id, parent_id = _ctx.inherited
-            else:
-                trace_id, parent_id = _new_id(), None
-            scopes = (_ctx.scopes if _ctx.scopes is not None
-                      else _active_scope_snapshot())
+        trace_id, parent_id, scopes = _open_context()
         s = Span(trace_id, _new_id(), parent_id, self.kind)
         s.scopes = scopes
         s.attrs.update(self._attrs)
@@ -348,6 +401,7 @@ class span:
             except Exception:
                 pass
         _ctx.stack.append(s)
+        self._annotation = _profiler_annotation(self.kind)
         s.start = time.perf_counter()
         self._span = s
         return s
@@ -355,6 +409,8 @@ class span:
     def __exit__(self, exc_type, exc, tb):
         s = self._span
         s.end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         if exc_type is not None:
             s.attrs.setdefault("error", exc_type.__name__)
         # pop by identity — a mis-nested exit must not corrupt the stack
@@ -362,11 +418,115 @@ class span:
             _ctx.stack.pop()
         elif s in _ctx.stack:
             _ctx.stack.remove(s)
-        _ring.push(s)
-        metric = SPAN_KINDS[self.kind]
-        if metric:
-            _observe_metric(metric, s.duration)
+        _record(s)
         return False
+
+
+# -- device stages ----------------------------------------------------------
+
+class device_span:
+    """One device stage's occupancy, recorded without blocking the host::
+
+        stage = tracing.device_span("bls_pairing")      # before dispatch
+        ok = stage.watch(k.pairing_check_batch(...))    # after dispatch
+
+    Construction stamps the dispatch time and the calling thread's trace
+    context; :meth:`watch` hands the stage's outputs to a watcher thread,
+    which waits for them (``jax.block_until_ready``) and pushes the span.
+    The span runs from the later of its dispatch and the end of the
+    previous device span to the moment the outputs are ready, so device
+    spans never overlap and each is its stage's device occupancy on the
+    host clock (the watcher stamps the time once it holds the GIL again,
+    within one switch interval).  Outputs that are JAX tracers
+    (``jax.make_jaxpr`` over the stage) record nothing and start no
+    watcher.  Device spans hold no profiler annotation: a profile already
+    holds the stage's programs."""
+
+    def __init__(self, kind: str):
+        assert kind in SPAN_KINDS, \
+            f"unknown span kind {kind!r} — register it in SPAN_KINDS"
+        self.kind = kind
+        self.trace_id, self.parent_id, self.scopes = _open_context()
+        self.recorded = threading.Event()
+        self.dispatched = time.perf_counter()
+
+    def watch(self, outputs):
+        """Record the stage once ``outputs`` are ready; returns them."""
+        import jax
+        if any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves(outputs)):
+            return outputs
+        _ctx.device_pending.append(self)
+        _device_watcher().put((self, outputs))
+        return outputs
+
+
+#: the watcher thread and its queue, once started
+_watcher: tuple[threading.Thread, queue.SimpleQueue] | None = None
+_watcher_lock = threading.Lock()
+
+
+def _device_watcher() -> queue.SimpleQueue:
+    """The queue of the process's one watcher thread, started on first
+    use.  One thread waits for the stages in the order they were watched,
+    which is the order one device runs them."""
+    global _watcher
+    with _watcher_lock:
+        if _watcher is None:
+            q: queue.SimpleQueue = queue.SimpleQueue()
+            thread = threading.Thread(target=_watch_device_spans, args=(q,),
+                                      name="device-span-watcher",
+                                      daemon=True)
+            thread.start()
+            _watcher = (thread, q)
+            atexit.register(_stop_device_watcher)
+        return _watcher[1]
+
+
+def _stop_device_watcher() -> None:
+    """Stop the watcher once it has recorded what it holds (at exit)."""
+    global _watcher
+    with _watcher_lock:
+        watcher, _watcher = _watcher, None
+    if watcher is not None:
+        thread, q = watcher
+        q.put(None)
+        thread.join(1.0)
+
+
+def _watch_device_spans(q: queue.SimpleQueue) -> None:
+    import jax
+    last_ready = 0.0
+    while (item := q.get()) is not None:
+        stage, outputs = item
+        try:
+            error = None
+            try:
+                jax.block_until_ready(outputs)
+            except Exception as e:      # a failed stage still ends its span
+                error = type(e).__name__
+            ready = time.perf_counter()
+            del item, outputs
+            s = Span(stage.trace_id, _new_id(), stage.parent_id, stage.kind)
+            s.scopes = stage.scopes
+            if error is not None:
+                s.attrs["error"] = error
+            s.start = min(max(stage.dispatched, last_ready), ready)
+            s.end = last_ready = ready
+            _record(s)
+        except Exception:               # the watcher outlives a bad record
+            pass
+        finally:
+            stage.recorded.set()
+
+
+def wait_device_spans() -> None:
+    """Wait until every device span this thread watched is recorded.
+    Cheap once the host has read the stages' outputs: the watcher then
+    only has to take the GIL."""
+    pending, _ctx.device_pending = _ctx.device_pending, []
+    for stage in pending:
+        stage.recorded.wait()
 
 
 # -- ring access / export ----------------------------------------------------

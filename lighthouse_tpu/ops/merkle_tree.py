@@ -84,7 +84,7 @@ def _build_fn(dense_depth: int, limit_depth: int, pre_levels: int,
     import jax
     import jax.numpy as jnp
 
-    def build(leaves, pk_blocks, n_live):
+    def levels_and_root(leaves, pk_blocks, n_live):
         nodes = _fold_pre(leaves, pre_levels, pk_blocks)
         if pre_levels > 0:
             live = (jnp.arange(nodes.shape[0]) < n_live)[:, None]
@@ -96,9 +96,14 @@ def _build_fn(dense_depth: int, limit_depth: int, pre_levels: int,
         root = _cap_root(levels[-1][0], dense_depth, limit_depth)
         return tuple(levels), root
 
-    if not with_pk:
-        return jax.jit(lambda leaves, n_live: build(leaves, None, n_live))
-    return jax.jit(build)
+    # named, so a device trace names the program
+    if with_pk:
+        def tree_build(leaves, pk_blocks, n_live):
+            return levels_and_root(leaves, pk_blocks, n_live)
+    else:
+        def tree_build(leaves, n_live):
+            return levels_and_root(leaves, None, n_live)
+    return jax.jit(tree_build)
 
 
 @functools.lru_cache(maxsize=None)
@@ -113,7 +118,7 @@ def _update_fn(dense_depth: int, limit_depth: int, pre_levels: int,
     """
     import jax
 
-    def update(levels, rows, new_leaves, pk_blocks=None):
+    def tree_update(levels, rows, new_leaves, pk_blocks=None):
         nodes = _fold_pre(new_leaves, pre_levels, pk_blocks)
         levels = list(levels)
         levels[0] = levels[0].at[rows].set(nodes)
@@ -126,12 +131,8 @@ def _update_fn(dense_depth: int, limit_depth: int, pre_levels: int,
         root = _cap_root(levels[-1][0], dense_depth, limit_depth)
         return tuple(levels), root
 
-    donate_args = (0,) if donate else ()
-    if not with_pk:
-        return jax.jit(lambda levels, rows, new_leaves:
-                       update(levels, rows, new_leaves),
-                       donate_argnums=donate_args)
-    return jax.jit(update, donate_argnums=donate_args)
+    # named, so a device trace names the program
+    return jax.jit(tree_update, donate_argnums=(0,) if donate else ())
 
 
 class DeviceTree:
