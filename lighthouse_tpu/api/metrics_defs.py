@@ -198,6 +198,13 @@ CATALOG: dict[str, tuple[str, str]] = {
     "bls_device_pairing_seconds":
         ("hist", "Device occupancy of the pairing check (Miller loop, "
                  "final exponentiation)"),
+    "bls_const_ladder_steps_total":
+        ("counter", "Doubling steps of the constant-scalar ladders (subgroup "
+                    "check, cofactor clearing, Miller loop) in dispatched "
+                    "BLS stage programs, per program, not per lane"),
+    "bls_const_ladder_adds_total":
+        ("counter", "Of those steps, the ones that run the addition (the "
+                    "constant's set bits); the rest skip it"),
     "tree_hash_root_seconds": ("hist", "BeaconState tree_hash latency"),
     # -- CoW state columns (containers/cow.py) ----------------------------
     "state_copy_seconds":

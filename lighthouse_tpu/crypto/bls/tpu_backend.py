@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import os
 import secrets
+import sys
 
 import numpy as np
 
@@ -263,6 +264,16 @@ def device_checks(prep: dict, lanes: int):
               else contextlib.nullcontext()):
             return k.scalars_to_bits(rands, RAND_BITS)
 
+    def count_ladders(*programs):
+        # the dispatched programs' constant-ladder steps and additions
+        md = sys.modules.get("lighthouse_tpu.api.metrics_defs")
+        if not live or md is None:
+            return
+        counts = [k.ladder_counts(c) for name in programs
+                  for c in k.CONST_LADDERS[name]]
+        md.count("bls_const_ladder_steps_total", sum(s for s, _ in counts))
+        md.count("bls_const_ladder_adds_total", sum(a for _, a in counts))
+
     # device: signature decompression + subgroup check (generator
     # padding keeps both checks uniformly True on padded lanes)
     stage = tracing.device_span("bls_decompress")
@@ -272,11 +283,14 @@ def device_checks(prep: dict, lanes: int):
     yield on_curve
     stage = tracing.device_span("bls_subgroup")
     one2 = jnp.asarray(np.broadcast_to(k.FP2_ONE, (lanes, 2, bi.NLIMBS)))
-    yield stage.watch(k.g2_in_subgroup_batch(sig_x, sig_y, one2))
+    in_subgroup = k.g2_in_subgroup_batch(sig_x, sig_y, one2)
+    count_ladders("g2_in_subgroup_batch")
+    yield stage.watch(in_subgroup)
 
     # device: hash unique messages to G2 (host did expand_message_xmd)
     stage = tracing.device_span("bls_hash_to_g2")
     mx, my, mz = k.hash_to_g2_batch_from_u(prep["u0"], prep["u1"])
+    count_ladders("_cc_mul_k1", "_cc_mul_k2_psi")
     msg_x, msg_y = stage.watch(k.jacobian_to_affine_fp2(mx, my, mz))
 
     one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
@@ -307,8 +321,9 @@ def device_checks(prep: dict, lanes: int):
         jnp.concatenate([msg_x, aax[None]], axis=0),
         jnp.concatenate([msg_y, aay[None]], axis=0)))
     stage = tracing.device_span("bls_pairing")
-    yield stage.watch(
-        k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"]))
+    verdict = k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"])
+    count_ladders("miller_loop_batch")
+    yield stage.watch(verdict)
 
 
 class TpuBackend(PythonBackend):

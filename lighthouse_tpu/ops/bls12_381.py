@@ -186,6 +186,19 @@ def scalars_to_bits(scalars: list[int], nbits: int) -> np.ndarray:
     return out
 
 
+def ladder_bits(k: int) -> np.ndarray:
+    """The bits of a constant k > 0 after its leading one, MSB first: the
+    steps of a ladder that starts from the point itself."""
+    return np.array([b == "1" for b in bin(k)[3:]], dtype=bool)
+
+
+def ladder_counts(k: int) -> tuple[int, int]:
+    """(steps, additions) of the constant ladder over k: one doubling per
+    step, an addition only on the steps whose bit is set."""
+    bits = ladder_bits(k)
+    return len(bits), int(bits.sum())
+
+
 # ---------------------------------------------------------------------------
 # Fp6 = Fp2[v]/(v^3 - xi); element [..., 3, 2, 32]
 # ---------------------------------------------------------------------------
@@ -333,22 +346,6 @@ def fp12_eq(a, b):
                   b.reshape(b.shape[:-4] + (12, bi.NLIMBS))), axis=-1)
 
 
-# generic pow by a fixed integer exponent (scan over bits, MSB first)
-def fp12_pow_const(f, exponent: int):
-    bits = np.array([int(b) for b in bin(exponent)[2:]], dtype=np.int32)
-
-    def step(acc, bit):
-        acc = fp12_square(acc)
-        withf = fp12_mul(acc, f)
-        out = jnp.where(bit, withf, acc)
-        return out, None
-
-    init = fp12_one_like(f.shape[:-4])
-    # first bit is always 1: start from f
-    out, _ = jax.lax.scan(step, f, jnp.asarray(bits[1:]))
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Fp inversion / exponentiation (scan)
 # ---------------------------------------------------------------------------
@@ -479,20 +476,18 @@ def _make_point_ops(add_, sub_, mul_, square_, muln_, neg_, is_zero_,
         return ax, ay, az
 
     def scalar_mul_const(x, y, z, k: int):
-        """Shared constant scalar (cofactor clearing, subgroup checks)."""
-        bits = np.array([int(b) for b in bin(k)[2:]], dtype=np.int32)
+        """Shared constant scalar (cofactor clearing, subgroup checks):
+        start from the point at k's leading bit, then double on every
+        later bit and add the point only on the set ones — the complete
+        addition sits under a branch on the bit, so a zero bit costs one
+        doubling (see :func:`ladder_counts`)."""
 
-        def step(carry, bit):
-            ax, ay, az = carry
-            ax, ay, az = dbl(ax, ay, az)
-            sx, sy, sz = add(ax, ay, az, x, y, z)
-            ax = where_nd(bit.astype(bool), sx, ax)
-            ay = where_nd(bit.astype(bool), sy, ay)
-            az = where_nd(bit.astype(bool), sz, az)
-            return (ax, ay, az), None
+        def step(acc, bit):
+            acc = dbl(*acc)
+            return jax.lax.cond(bit, lambda a: add(*a, x, y, z),
+                                lambda a: a, acc), None
 
-        (ax, ay, az), _ = jax.lax.scan(
-            step, (x, y, jnp.zeros_like(z)), jnp.asarray(bits))
+        (ax, ay, az), _ = jax.lax.scan(step, (x, y, z), ladder_bits(k))
         return ax, ay, az
 
     return dbl, add, scalar_mul, scalar_mul_const
@@ -628,7 +623,6 @@ def g2_sum(x, y, z, width: int = 128):
 # ---------------------------------------------------------------------------
 
 _X_ABS = abs(X_PARAM)
-_X_BITS = np.array([int(b) for b in bin(_X_ABS)[2:]], dtype=np.int32)
 # constants precomputed at import (never inside a trace)
 _TWO_INV = fp_const(pow(2, P_INT - 2, P_INT))
 _B_TWIST_3 = fp2_const(12, 12)  # 3 * (4 + 4u)
@@ -686,8 +680,9 @@ def miller_loop_batch(px, py, qx, qy):
     """f_i = miller(P_i, Q_i) for a batch of affine pairs.
 
     px, py: Fp [n, 32]; qx, qy: Fp2 [n, 2, 32]. Returns Fp12 [n, ...].
-    The x-bit pattern is constant, so the loop is a lax.scan whose body
-    always computes the add-step and selects it in on set bits.
+    The x-bit pattern is constant, so the loop is a lax.scan over its
+    bits whose add step and line evaluation sit under a branch on the
+    bit: they run on the set bits only.
     """
     n = px.shape[0]
     two_inv = jnp.asarray(_TWO_INV)
@@ -698,23 +693,20 @@ def miller_loop_batch(px, py, qx, qy):
     tx, ty = qx, qy
     tz = jnp.broadcast_to(jnp.asarray(FP2_ONE), qx.shape) + (qx & jnp.int32(0))
 
-    bits = jnp.asarray(_X_BITS[1:])
+    def add_step(carry):
+        f, tx, ty, tz = carry
+        t, coeffs = _miller_add_step(tx, ty, tz, qx, qy)
+        return (_ell(f, coeffs, px, py), *t)
 
     def step(carry, bit):
         f, tx, ty, tz = carry
         f = fp12_square(f)
         (tx, ty, tz), coeffs = _miller_dbl_step(tx, ty, tz, two_inv)
         f = _ell(f, coeffs, px, py)
-        (ax, ay, az), acoeffs = _miller_add_step(tx, ty, tz, qx, qy)
-        fa = _ell(f, acoeffs, px, py)
-        use = bit.astype(bool)
-        f = jnp.where(use, fa, f)
-        tx = jnp.where(use, ax, tx)
-        ty = jnp.where(use, ay, ty)
-        tz = jnp.where(use, az, tz)
-        return (f, tx, ty, tz), None
+        return jax.lax.cond(bit, add_step, lambda c: c,
+                            (f, tx, ty, tz)), None
 
-    (f, _, _, _), _ = jax.lax.scan(step, (f, tx, ty, tz), bits)
+    (f, _, _, _), _ = jax.lax.scan(step, (f, tx, ty, tz), ladder_bits(_X_ABS))
     # x < 0: conjugate
     return fp12_conj(f)
 
@@ -1176,3 +1168,13 @@ def g2_in_subgroup_batch(x, y, z):
     px, py, pz = psi_g2(x, y, z)
     ux, uy, uz = g2_scalar_mul_const(x, y, z, _U_ABS2)
     return g2_eq_jac(px, py, pz, ux, fp2_neg(uy), uz)
+
+
+#: stage program name -> the constants of the ladders it runs (each
+#: costs :func:`ladder_counts` steps and additions per lane)
+CONST_LADDERS = {
+    "g2_in_subgroup_batch": (_U_ABS2,),
+    "_cc_mul_k1": (_BP_K1,),
+    "_cc_mul_k2_psi": (_BP_K2,),
+    "miller_loop_batch": (_X_ABS,),
+}

@@ -1,4 +1,5 @@
 """TPU BLS12-381 kernels vs the pure-Python oracle."""
+import jax
 import numpy as np
 import pytest
 
@@ -125,16 +126,138 @@ def _f12_to_ints(e):
 
 def test_miller_loop_matches_python():
     """Miller loop only (final exp is covered by the slow test — its scans
-    take minutes on the CPU test backend but milliseconds per batch on TPU)."""
+    take minutes on the CPU test backend but milliseconds per batch on TPU).
+    Each pair's own value, then their product."""
     from lighthouse_tpu.crypto.bls12_381.pairing import miller_loop
     pairs = [(G1_GENERATOR.mul(3), G2_GENERATOR.mul(5)),
              (G1_GENERATOR.mul(2), G2_GENERATOR.mul(9))]
     px, py = _encode_g1([p for p, _ in pairs])
     qx, qy = _encode_g2([q for _, q in pairs])
     fs = k.miller_loop_batch(px, py, qx, qy)
+    for i, pair in enumerate(pairs):
+        assert k.fp_decode(fs[i]) == _f12_to_ints(miller_loop([pair]))
     prod = k.fp12_product(fs)
     want = miller_loop(pairs)
     assert k.fp_decode(prod) == _f12_to_ints(want)
+
+
+def _twist_points():
+    """On-curve G2 points (y^2 = x^3 + b over Fp2, x = (1, 0), (2, 0)
+    ...): outside the subgroup as a rule."""
+    from lighthouse_tpu.crypto.bls12_381.curve import B_G2, G2Point
+    xx = 0
+    while True:
+        xx += 1
+        yy = (Fp2(xx, 0) * Fp2(xx, 0) * Fp2(xx, 0) + B_G2).sqrt()
+        if yy is not None:
+            yield G2Point(Fp2(xx, 0), yy)
+
+
+_const_ladder = jax.jit(k.g2_scalar_mul_const, static_argnums=3)
+
+
+@pytest.mark.parametrize("name", ["u", "k1", "k2"])
+def test_const_ladder_matches_oracle(name):
+    """[c]P for the subgroup check's |u| and the cofactor clearing's k1,
+    k2: on subgroup points, a point outside the subgroup, one of order
+    13 (the ladder meets P == acc and P == -acc there) and infinity."""
+    from lighthouse_tpu.crypto.bls12_381.curve import H_EFF_G2, Point, R
+    constant = {"u": k._U_ABS2, "k1": k._BP_K1, "k2": k._BP_K2}[name]
+    off = next(_twist_points())
+    assert not off.mul(R).is_infinity()
+    # the twist's 13-torsion is Z/13 x Z/13: [h2 r / 13^2] leaves a
+    # point's part of order 13
+    order_13 = next(q for q in (p.mul(H_EFF_G2 * R // 13**2)
+                                for p in _twist_points())
+                    if not q.is_infinity())
+    assert order_13.mul(13).is_infinity()
+    points = [G2_GENERATOR.mul(int(s)) for s in rng.integers(1, 2**62, 2)]
+    points += [off, order_13]
+    x, y = _encode_g2(points + [G2_GENERATOR])
+    z = np.array(np.broadcast_to(k.FP2_ONE, x.shape))
+    z[-1] = 0                                   # the last lane: infinity
+    points.append(Point.infinity(G2_GENERATOR.b))
+    out = [np.asarray(v).reshape(len(points), 2, -1)
+           for v in _const_ladder(x, y, z, constant)]
+    for i, point in enumerate(points):
+        got = Point(*(Fp2(*k.fp_decode(v[i])) for v in out), G2_GENERATOR.b)
+        assert got.eq(point.mul(constant)), (name, i)
+
+
+def _mont_mul_calls(lowered):
+    """The Montgomery products of a lowered program (calls of the
+    ``mont_mul`` functions, counted through every other call): in all,
+    and per ``while`` loop, with the products of each branch of every
+    ``case`` inside that loop."""
+    module = lowered.compiler_ir("stablehlo")
+    funcs = {str(op.attributes["sym_name"]).strip('"'): op.operation
+             for op in module.body.operations}
+
+    def callee(op):
+        return str(op.attributes["callee"]).lstrip("@")
+
+    def walk(op):
+        for region in op.regions:
+            yield from walk_region(region)
+
+    def walk_region(region):
+        for block in region.blocks:
+            for child in block.operations:
+                yield child
+                if child.name != "func.call":
+                    yield from walk(child)
+                elif not callee(child).startswith("mont_mul"):
+                    yield from walk(funcs[callee(child)])
+
+    def products(ops):
+        return sum(o.name == "func.call" and callee(o).startswith("mont_mul")
+                   for o in ops)
+
+    main = funcs["main"]
+    return products(walk(main)), [
+        (products(walk(loop)),
+         [[products(walk_region(r)) for r in case.regions]
+          for case in walk(loop) if case.name == "stablehlo.case"])
+        for loop in walk(main) if loop.name == "stablehlo.while"]
+
+
+def _add_step_and_line(f, tx, ty, tz, qx, qy, px, py):
+    t, coeffs = k._miller_add_step(tx, ty, tz, qx, qy)
+    return k._ell(f, coeffs, px, py), t
+
+
+def _dbl_step_and_line(f, tx, ty, tz, px, py):
+    t, coeffs = k._miller_dbl_step(tx, ty, tz, k._TWO_INV)
+    return k._ell(k.fp12_square(f), coeffs, px, py), t
+
+
+@pytest.mark.parametrize("program", ["g2_in_subgroup_batch",
+                                     "miller_loop_batch"])
+def test_ladder_addition_sits_under_a_branch(program):
+    """The lowered program's one loop holds one two-way branch on the
+    constant's bit: one arm computes no product, the other exactly the
+    addition's (``g2_add``; the Miller add step and its line), and the
+    rest of the loop body exactly the doubling's.  Turned back into
+    always-add-then-select, the branch and its empty arm go."""
+    fp, fp2, fp12 = (jax.ShapeDtypeStruct(s, np.int32) for s in
+                     ((1, 32), (1, 2, 32), (1, 2, 3, 2, 32)))
+    if program == "g2_in_subgroup_batch":
+        args = (fp2, fp2, fp2)
+        addition = jax.jit(k.g2_add).lower(*args, *args)
+        doubling = jax.jit(k.g2_dbl).lower(*args)
+    else:
+        args = (fp, fp, fp2, fp2)
+        addition = jax.jit(_add_step_and_line).lower(
+            fp12, fp2, fp2, fp2, fp2, fp2, fp, fp)
+        doubling = jax.jit(_dbl_step_and_line).lower(
+            fp12, fp2, fp2, fp2, fp, fp)
+    add_products, _ = _mont_mul_calls(addition)
+    dbl_products, _ = _mont_mul_calls(doubling)
+    _, loops = _mont_mul_calls(getattr(k, program).lower(*args))
+    ((in_loop, cases),) = loops
+    (arms,) = cases
+    assert sorted(arms) == [0, add_products]
+    assert in_loop == dbl_products + add_products
 
 
 def test_final_exp_matches_python():

@@ -4,7 +4,8 @@
   shapes (no BLS compile), a verify records the host stages and the five
   device stages inside ``bls_batch_verify``, device stages in order and
   never overlapping; a failed first check stops at ``bls_decompress``;
-  ``make_jaxpr`` over the device half records nothing.
+  ``make_jaxpr`` over the device half records nothing; a verify feeds
+  the constant-ladder counters with each program's static counts.
 - Block import: the four new kinds nest in ``block_import``.
 - Profiler clock: a host span holds a ``lighthouse_tpu:<kind>``
   annotation of the same length in a JAX profile.
@@ -146,6 +147,25 @@ def test_make_jaxpr_over_the_device_half_records_nothing(
         tb.device_checks({**prep, **a}, small)))(arrays)
     assert tracing.snapshot() == []
     assert tracing._watcher is None             # no watcher started
+
+
+def test_verify_counts_constant_ladder_steps_and_additions(stand_ins, tpu):
+    """Each dispatch of a program with a constant ladder adds its static
+    steps and additions: the subgroup check's |u|, the cofactor
+    clearing's k1 and k2, the Miller loop's |x|."""
+    from lighthouse_tpu.api import metrics, metrics_defs
+    from lighthouse_tpu.ops import bls12_381 as k
+    names = ("bls_const_ladder_steps_total", "bls_const_ladder_adds_total")
+    assert all(name in metrics_defs.CATALOG for name in names)
+    before = [metrics.counter_value(name) for name in names]
+    verdict, _ = _verify()
+    assert verdict is True
+    steps, adds = (metrics.counter_value(name) - was
+                   for name, was in zip(names, before))
+    constants = [k._U_ABS2, k._BP_K1, k._BP_K2, abs(k.X_PARAM)]
+    assert [k.ladder_counts(c) for c in constants] == [
+        (63, 5), (127, 37), (63, 6), (63, 5)]
+    assert (steps, adds) == (63 + 127 + 63 + 63, 5 + 37 + 6 + 5)
 
 
 def test_device_span_of_a_failed_stage_still_ends(monkeypatch):

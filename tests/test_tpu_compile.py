@@ -94,3 +94,15 @@ def test_bls_g1_scalar_mul_128_lanes(one_chip):
     _compile_fits(k.g1_scalar_mul_jit, fp, fp, fp,
                   jax.ShapeDtypeStruct((128, 64), jnp.int32,
                                        sharding=one_chip))
+
+
+def test_bls_subgroup_check_branches_on_the_bit_128_lanes(one_chip):
+    """The chip's compiler keeps the |u| ladder's addition under one
+    branch inside the loop: no pass turns it back into a select."""
+    fp2 = jax.ShapeDtypeStruct((128, 2, bi.NLIMBS), jnp.int32,
+                               sharding=one_chip)
+    compiled = k.g2_in_subgroup_batch.lower(fp2, fp2, fp2).compile()
+    branches = [line for line in compiled.as_text().splitlines()
+                if " conditional(" in line]
+    assert len(branches) == 1, branches
+    assert "/while/body/" in branches[0]
