@@ -198,6 +198,20 @@ CATALOG: dict[str, tuple[str, str]] = {
     "bls_device_pairing_seconds":
         ("hist", "Device occupancy of the pairing check (Miller loop, "
                  "final exponentiation)"),
+    "bls_device_pk_aggregate_seconds":
+        ("hist", "Device occupancy of the multi-key sets' pubkey sums "
+                 "(table gather and bucket sums)"),
+    "bls_pk_table_seconds":
+        ("hist", "Loading or growing the device pubkey table: key "
+                 "validation and the writes to the device"),
+    "bls_pubkeys_aggregated_total":
+        ("counter", "Pubkeys of multi-key signature sets summed on the "
+                    "device"),
+    "bls_key_lanes_padded_total":
+        ("counter", "Key lanes of the device pubkey sums that held no key "
+                    "(bucket and chunk padding)"),
+    "bls_pubkey_table_rows":
+        ("gauge", "Rows of the device pubkey table (validated pubkeys)"),
     "bls_const_ladder_steps_total":
         ("counter", "Doubling steps of the constant-scalar ladders (subgroup "
                     "check, cofactor clearing, Miller loop) in dispatched "

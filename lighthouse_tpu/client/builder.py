@@ -93,8 +93,7 @@ class ClientBuilder:
         cfg = self.config
         client = Client()
         client.env = self.env
-        # compile a device backend's programs now, not in the first batches
-        bls.set_backend(cfg.crypto_backend).precompile()
+        backend = bls.set_backend(cfg.crypto_backend)
 
         # store
         if cfg.datadir:
@@ -160,6 +159,11 @@ class ClientBuilder:
         if cfg.suggested_fee_recipient is not None:
             client.chain.default_fee_recipient = cfg.suggested_fee_recipient
         registry = client.chain.head().head_state.validators
+        # the validator pubkey cache of a device backend, filled in bulk;
+        # then its programs compiled at the table's loaded shape, not in
+        # the first batches
+        backend.load_pubkeys(registry.pubkeys)
+        backend.precompile()
         for pk in cfg.validator_monitor_pubkeys:
             idx = registry.index_of(pk)
             if idx is not None:

@@ -54,6 +54,12 @@ class BlsBackend:
         (a node calls this at start-up); host backends have none."""
         return []
 
+    def load_pubkeys(self, pubkeys) -> int:
+        """Load a registry's pubkeys (an (n, 48) array) into the backend's
+        own table at start-up, returning the rows added; backends that
+        keep none add nothing."""
+        return 0
+
     def aggregate_signatures(self, sigs: list) -> bytes:
         raise NotImplementedError
 

@@ -50,6 +50,9 @@ def _load_lib():
     lib.bls_aggregate_pks.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p]
     lib.bls_validate_pubkey.restype = C.c_int
     lib.bls_validate_pubkey.argtypes = [C.c_char_p]
+    lib.bls_g1_decompress_batch.restype = C.c_int
+    lib.bls_g1_decompress_batch.argtypes = [
+        C.c_size_t, C.c_void_p, C.c_void_p, C.c_void_p, C.c_size_t]
     # KZG surface (crypto/kzg.py host acceleration)
     lib.kzg_g1_msm.restype = C.c_int
     lib.kzg_g1_msm.argtypes = [C.c_size_t, C.c_char_p, C.c_char_p,

@@ -4,9 +4,12 @@ The `tpu` entry in the backend registry (--crypto-backend=tpu), mirroring how
 the reference selects `blst` (crypto/bls/src/lib.rs:86-141). Pipeline for a
 batch of sets:
 
-  host:   parse+range-check compressed bytes, aggregate cached pubkeys,
+  host:   parse+range-check compressed bytes, pubkeys from the point
+          cache (single-key batches) or as rows of the device pubkey
+          table (``pubkey_table.py``; batches with a multi-key set),
           expand_message_xmd (a few SHA-256 calls per message)
-  device: batched G2 signature decompression (sqrt + sign select), psi
+  device: table rows gathered and summed per set (``g1_bucket_sum``),
+          batched G2 signature decompression (sqrt + sign select), psi
           subgroup checks, SSWU+isogeny+cofactor hash-to-G2, RLC 64-bit
           scalar muls, signature tree-aggregation, n+1 Miller loops, ONE
           final exponentiation.
@@ -81,6 +84,17 @@ def static_lanes() -> int:
     return lane_options()[1]
 
 
+def key_shape() -> tuple[int, int]:
+    """(depth, buckets) of one pubkey-aggregation chunk: a bucket holds up
+    to ``depth`` keys of one set and a chunk ``buckets`` buckets, the
+    last kept empty.  16 x 2,304 on accelerators: a full mainnet block at
+    2^20 validators (65 sets of 512 keys, 2,080 buckets) is one chunk,
+    and the 16 + 12 sequential additions of :func:`g1_bucket_sum` run
+    over 2,304 lanes; 4 x 32 on the XLA CPU fallback."""
+    import jax
+    return (16, 2304) if jax.default_backend() != "cpu" else (4, 32)
+
+
 class _PadCache:
     """Constant device inputs for padding lanes, built once per lane
     count: generator signature x/flag, generator pubkey limbs, and the
@@ -116,28 +130,30 @@ _PAD: _PadCache | None = None
 
 def parse_sets(backend, sets):
     """Host parse shared by the single-device and mesh-sharded verifiers:
-    per-set pubkey aggregation (cached registry points) + compressed-
-    signature x/flag extraction with range checks.  Returns
-    (pks, sig_xs, flags, msgs) or None when any set is malformed (the
-    batch must verify False, not raise)."""
+    compressed-signature x/flag extraction with range checks, and the
+    pubkeys.  A batch of single-key sets takes its points from the point
+    cache (``backend._pk``); a batch with a multi-key set takes every
+    set's keys as rows of the device pubkey table (``backend.table``),
+    summed on the device.  Returns (pks, sig_xs, flags, msgs, rows) —
+    per set, a point and None, or None and its table rows — or None when
+    any set is malformed (the batch must verify False, not raise)."""
     with tracing.span("bls_parse"):
         return _parse_sets(backend, sets)
 
 
 def _parse_sets(backend, sets):
     from ..bls12_381.fields import P as P_INT
+    on_table = any(len(s.pubkeys) > 1 for s in sets)
     pks, sig_xs, flags, msgs = [], [], [], []
     try:
         for s in sets:
             if not s.pubkeys:
                 return None
-            pts = [backend._pk(p) for p in s.pubkeys]
-            agg = pts[0]
-            for p in pts[1:]:
-                agg = agg.add(p)
-            if agg.is_infinity():
-                return None
-            pks.append(agg)
+            if not on_table:
+                pk = backend._pk(s.pubkeys[0])
+                if pk.is_infinity():
+                    return None
+                pks.append(pk)
             cb = s.signature
             if len(cb) != 96 or not (cb[0] & 0x80) or (cb[0] & 0x40):
                 return None           # malformed or infinity signature
@@ -150,19 +166,31 @@ def _parse_sets(backend, sets):
             msgs.append(s.message)
     except ValueError:
         return None
-    return pks, sig_xs, flags, msgs
+    if not on_table:
+        return pks, sig_xs, flags, msgs, [None] * len(sets)
+    # every key in one table lookup (unknown keys are validated and
+    # added in one native call; an invalid one fails the batch)
+    flat = backend.table.rows_of([pk for s in sets for pk in s.pubkeys])
+    if flat is None:
+        return None
+    rows = np.split(flat, np.cumsum([len(s.pubkeys) for s in sets])[:-1])
+    return [None] * len(sets), sig_xs, flags, msgs, rows
 
 
-def host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
+def host_prepare(pks, sig_xs, sig_flags, msgs, rows, lanes: int,
+                 small: int):
     """Pad/group host prep shared by both verifiers: same-message
-    grouping (segment layout for `g1_segment_sum`), RLC scalars, and the
+    grouping (segment layout for `g1_segment_sum`), RLC scalars, the
     padded device input arrays (cached generator constants on padding
-    lanes).  Returns a dict of arrays + layout."""
+    lanes) and, where sets carry table rows, the aggregation layout
+    (:func:`aggregation_layout`).  Returns a dict of arrays + layout."""
     with tracing.span("bls_prepare"):
-        return _host_prepare(pks, sig_xs, sig_flags, msgs, lanes, small)
+        return _host_prepare(pks, sig_xs, sig_flags, msgs, rows, lanes,
+                             small)
 
 
-def _host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
+def _host_prepare(pks, sig_xs, sig_flags, msgs, rows, lanes: int,
+                  small: int):
     import secrets
 
     from ...ops import bigint as bi
@@ -200,14 +228,20 @@ def _host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
     sig_x = cat([sig_x_real, _PAD.tile(_PAD.sig_x, pad)]) if pad \
         else sig_x_real
     flags = np.asarray(list(sig_flags) + [_PAD.flag] * pad, dtype=bool)
-    pkx_l, pky_l = [], []
-    for p in (pks[i] for i in order):
-        x, y = p.to_affine()
-        pkx_l.append(int(x))
-        pky_l.append(int(y))
-    pk_x_real, pk_y_real = k.fp_encode(pkx_l), k.fp_encode(pky_l)
-    pk_x = cat([pk_x_real, _PAD.tile(_PAD.pk_x, pad)]) if pad else pk_x_real
-    pk_y = cat([pk_y_real, _PAD.tile(_PAD.pk_y, pad)]) if pad else pk_y_real
+    if rows[0] is None:
+        pkx_l, pky_l = [], []
+        for p in (pks[i] for i in order):
+            x, y = p.to_affine()
+            pkx_l.append(int(x))
+            pky_l.append(int(y))
+        pk_x_real, pk_y_real = k.fp_encode(pkx_l), k.fp_encode(pky_l)
+        pk_x = cat([pk_x_real, _PAD.tile(_PAD.pk_x, pad)]) if pad \
+            else pk_x_real
+        pk_y = cat([pk_y_real, _PAD.tile(_PAD.pk_y, pad)]) if pad \
+            else pk_y_real
+        keys = {"pk_x": pk_x, "pk_y": pk_y}
+    else:                             # summed on the device
+        keys = aggregation_layout([rows[i] for i in order], lanes)
     umsgs = [None] * n_groups
     for msg, g in groups.items():
         umsgs[g] = msg
@@ -219,13 +253,58 @@ def _host_prepare(pks, sig_xs, sig_flags, msgs, lanes: int, small: int):
     mask[:n_groups] = True
     mask[-1] = True                   # the aggregate/-G1 lane is real
     return {
-        "sig_x": sig_x, "flags": flags, "pk_x": pk_x, "pk_y": pk_y,
-        "u0": u0, "u1": u1, "starts": starts, "ends": ends, "mask": mask,
+        "sig_x": sig_x, "flags": flags, **keys, "u0": u0, "u1": u1,
+        "starts": starts, "ends": ends, "mask": mask,
         "pk_rands": [rands[i] for i in order] + [0] * pad,
         "sig_rands": list(rands) + [0] * pad,
         "neg_g_x": _PAD.neg_g_x, "neg_g_y": _PAD.neg_g_y,
         "n_groups": n_groups, "msg_lanes": msg_lanes,
     }
+
+
+def aggregation_layout(set_rows: list, lanes: int) -> dict:
+    """Device inputs of the per-set pubkey sums: ``set_rows`` holds each
+    set's table rows, set lane by set lane.  Each set's keys fill buckets
+    of ``depth`` (:func:`key_shape`), a set's last bucket padded with the
+    identity; the buckets fill chunks of ``buckets - 1``, a set crossing
+    a chunk boundary getting a partial sum in each.  Per chunk c:
+    ``agg_rows[c]``/``agg_live[c]`` [depth, buckets] (row, real key),
+    ``agg_starts[c]`` [buckets] (1 at a set's first bucket in the chunk,
+    and at the empty last one), ``agg_ends[c]`` [lanes] (each lane's last
+    bucket in the chunk, else the empty one); ``agg_multi`` [lanes]
+    marks the sets of several keys, whose sums must not be the
+    identity."""
+    depth, buckets = key_shape()
+    per = buckets - 1
+    counts = np.array([len(r) for r in set_rows])
+    nb = -(-counts // depth)                   # buckets per set
+    total = int(nb.sum())
+    chunks = -(-total // per)
+    # each key's global bucket and place in it
+    in_set = np.arange(counts.sum()) - np.repeat(np.cumsum(counts) - counts,
+                                                 counts)
+    gb = np.repeat(np.cumsum(nb) - nb, counts) + in_set // depth
+    rows = np.zeros((chunks, depth, buckets), np.int32)
+    live = np.zeros((chunks, depth, buckets), bool)
+    at = (gb // per, in_set % depth, gb % per)
+    rows[at] = np.concatenate(set_rows)
+    live[at] = True
+    # each bucket's set; segments start at a set's first bucket and at
+    # each chunk's first
+    owner = np.repeat(np.arange(len(counts)), nb)
+    b = np.arange(total)
+    first = np.r_[True, owner[1:] != owner[:-1]] | (b % per == 0)
+    last = np.r_[owner[1:] != owner[:-1], True] | (b % per == per - 1)
+    starts = np.zeros((chunks, buckets), np.int32)
+    starts[:, per] = 1
+    starts[b // per, b % per] = first
+    ends = np.full((chunks, lanes), per, np.int32)
+    ends[b[last] // per, owner[last]] = b[last] % per
+    multi = np.zeros(lanes, bool)
+    multi[:len(counts)] = counts > 1
+    return {"agg_rows": rows, "agg_live": live, "agg_starts": starts,
+            "agg_ends": ends, "agg_multi": multi,
+            "agg_keys": int(counts.sum())}
 
 
 #: threads compiling stage programs at start-up: 6 peaked at 19.9 GB of
@@ -236,9 +315,10 @@ COMPILE_THREADS = 6
 def device_checks(prep: dict, lanes: int):
     """The device half of one chunk, prepared by :func:`host_prepare`:
     yields, in order, the signatures' on-curve flags, their subgroup
-    flags and the batch pairing verdict.  A generator, so
-    ``_verify_chunk`` stops at the first failed check and
-    ``TpuBackend.precompile`` traces all three to find the programs.
+    flags, where a set has several keys the flags of :func:`pubkey_sums`
+    (no sum is the identity), and the batch pairing verdict.  A
+    generator, so ``_verify_chunk`` stops at the first failed check and
+    ``TpuBackend.precompile`` traces them all to find the programs.
 
     SAME-MESSAGE AGGREGATION (PERF_MODEL.md §3.1): sets sharing a
     message are folded into one pairing pair via
@@ -274,6 +354,12 @@ def device_checks(prep: dict, lanes: int):
         md.count("bls_const_ladder_steps_total", sum(s for s, _ in counts))
         md.count("bls_const_ladder_adds_total", sum(a for _, a in counts))
 
+    # device: the sets' pubkeys, summed from the table's rows where a
+    # set has several keys (dispatched first; their identity check is
+    # read after the subgroup check, once the device is busy with later
+    # stages)
+    px, py, pz, pk_ok = pubkey_sums(prep, lanes)
+
     # device: signature decompression + subgroup check (generator
     # padding keeps both checks uniformly True on padded lanes)
     stage = tracing.device_span("bls_decompress")
@@ -286,6 +372,8 @@ def device_checks(prep: dict, lanes: int):
     in_subgroup = k.g2_in_subgroup_batch(sig_x, sig_y, one2)
     count_ladders("g2_in_subgroup_batch")
     yield stage.watch(in_subgroup)
+    if pk_ok is not None:
+        yield pk_ok                   # no multi-key set sums to the identity
 
     # device: hash unique messages to G2 (host did expand_message_xmd)
     stage = tracing.device_span("bls_hash_to_g2")
@@ -293,13 +381,10 @@ def device_checks(prep: dict, lanes: int):
     count_ladders("_cc_mul_k1", "_cc_mul_k2_psi")
     msg_x, msg_y = stage.watch(k.jacobian_to_affine_fp2(mx, my, mz))
 
-    one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
-
     # RLC scaling (padded lanes scale to infinity)
     pk_bits = scalar_bits(prep["pk_rands"])
     stage = tracing.device_span("bls_rlc")
-    spx, spy, spz = k.g1_scalar_mul_jit(
-        prep["pk_x"], prep["pk_y"], one1, pk_bits)
+    spx, spy, spz = k.g1_scalar_mul_jit(px, py, pz, pk_bits)
     ssx, ssy, ssz = k.g2_scalar_mul_jit(
         sig_x, sig_y, one2, scalar_bits(prep["sig_rands"]))
     # per-message pubkey sums (segmented log-depth reduction);
@@ -326,61 +411,145 @@ def device_checks(prep: dict, lanes: int):
     yield stage.watch(verdict)
 
 
+def pubkey_sums(prep: dict, lanes: int):
+    """Each set lane's pubkey as Jacobian (x, y, z): points the host
+    encoded, or every set's keys gathered from the device pubkey table
+    (``prep["pk_table"]``) and summed there, one chunk of
+    :func:`aggregation_layout` per ``g1_bucket_sum`` (the
+    ``bls_pk_aggregate`` device span).  Returns (x, y, z, ok): ``ok`` is
+    None for host points, else per lane False where a multi-key set's
+    sum is the identity (the batch verifies False)."""
+    import jax
+
+    from ...ops import bigint as bi
+    from ...ops import bls12_381 as k
+    if "agg_rows" not in prep:
+        one1 = np.broadcast_to(k.FP_ONE, (lanes, bi.NLIMBS))
+        return prep["pk_x"], prep["pk_y"], one1, None
+    stage = tracing.device_span("bls_pk_aggregate")
+    tx, ty = prep["pk_table"]
+    for c in range(prep["agg_rows"].shape[0]):
+        kx, ky = k.g1_table_gather(tx, ty, prep["agg_rows"][c])
+        sums = k.g1_bucket_sum(kx, ky, prep["agg_live"][c],
+                               prep["agg_starts"][c], prep["agg_ends"][c],
+                               prep["agg_multi"])
+        if c:
+            sums = k.g1_sum_merge(*out[:3], *sums[:3], prep["agg_multi"])
+        out = sums
+    stage.watch(out)
+    md = sys.modules.get("lighthouse_tpu.api.metrics_defs")
+    if md is not None and not isinstance(prep["sig_x"], jax.core.Tracer):
+        md.count("bls_pubkeys_aggregated_total", prep["agg_keys"])
+        md.count("bls_key_lanes_padded_total",
+                 prep["agg_rows"].size - prep["agg_keys"])
+    return out
+
+
+def compile_stage_programs(cases, threads: int = COMPILE_THREADS) -> list:
+    """Compile (or load from the persistent cache) the stage programs that
+    :func:`device_checks` dispatches on each ``(prep, lanes)`` of
+    ``cases``, and no other shape, in ``threads`` threads: the programs
+    are found by tracing the device half on each prep, so they are
+    exactly those a verify of that shape dispatches to; compiling them
+    fills the executable cache that dispatch reads.  A prep's
+    ``pk_table`` may be the table's arrays or their shapes
+    (``pubkey_table.loaded_shape``), so a node can compile before its
+    table is loaded.  The compiler releases the GIL, so threads overlap.
+
+    Where JAX keeps a persistent compilation cache, the list of programs
+    per prep shape and each program's exported module are kept beside it
+    (``ops/stages.py``): a later start-up reads them instead of tracing
+    and lowering the kernels, and registers the executables on the stage
+    programs, which a verify then runs.  Returns
+    ``(name, jax.stages.Compiled)`` per program."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+
+    from ...ops import bls12_381 as k
+    from ...ops import stages
+
+    jitted = {}
+    for f in vars(k).values():
+        if isinstance(f, stages.Stage):
+            if jitted.setdefault(f.__name__, f) is not f:
+                raise RuntimeError(f"two stage programs are named "
+                                   f"{f.__name__!r}")
+    store = stages.store_dir() if stages.pristine() else None
+    jobs = {}
+    for prep, lanes in cases:
+        traced_args = {n: v for n, v in prep.items()
+                       if isinstance(v, np.ndarray) or n == "pk_table"}
+        list_key = (lanes, sorted(
+            (n, stages.signature(jax.tree.leaves(v)))
+            for n, v in traced_args.items()))
+        found = stages.read_list(store, list_key) if store else None
+        if found is None:
+            traced = jax.make_jaxpr(lambda a: list(
+                device_checks({**prep, **a}, lanes)))(traced_args)
+            found = [(eqn.params["name"], tuple(jax.ShapeDtypeStruct(
+                v.aval.shape, v.aval.dtype, weak_type=v.aval.weak_type)
+                for v in eqn.invars)) for eqn in traced.eqns
+                if eqn.params.get("name") in jitted]   # not eager glue
+            if store:
+                stages.write_list(store, list_key, found)
+        for name, args in found:
+            key = (name, tuple((a.shape, a.dtype) for a in args))
+            jobs[key] = (name, jitted[name], args)
+
+    def compile_one(fn, args):
+        if store:
+            return stages.compile_stored(fn, args, store)
+        return fn.lower(*args).compile()
+
+    with ThreadPoolExecutor(max_workers=threads) as pool:
+        futures = [(name, pool.submit(compile_one, fn, args))
+                   for name, fn, args in jobs.values()]
+        return [(name, f.result()) for name, f in futures]
+
+
 class TpuBackend(PythonBackend):
     name = "tpu"
+
+    def __init__(self):
+        super().__init__()
+        from .pubkey_table import PubkeyTable
+        self.table = PubkeyTable()
+
+    def load_pubkeys(self, pubkeys) -> int:
+        """Bulk-load a registry's pubkeys into the device pubkey table
+        (start-up); returns the rows added."""
+        return self.table.load(pubkeys)
 
     def precompile(self) -> list:
         """Compile every stage program of both static lane shapes (with
         the messages at the small shape: same-message gossip and
-        block-sized batches) in ``COMPILE_THREADS`` threads.  The
-        programs are found by tracing :func:`device_checks` on a
-        padded one-set chunk of each shape, so they are exactly those a
-        verify dispatches to; compiling them fills the executable cache
-        that dispatch reads.  One after another inside a cold node's
-        first batches they took ~15 minutes of TPU compiles (PR 21's
-        chip run); the compiler releases the GIL, so threads overlap
-        them.  Returns ``(name, jax.stages.Compiled)`` per program."""
-        from concurrent.futures import ThreadPoolExecutor
+        block-sized batches), traced on a padded one-set chunk of each
+        shape, with host points and with keys from the pubkey table at
+        its present shape: a node calls this once its registry's keys
+        are loaded (:meth:`load_pubkeys`).  One after another inside a
+        cold node's first batches they took ~15 minutes of compiles on
+        a v5e.  Returns ``(name, jax.stages.Compiled)`` per program."""
+        import itertools
 
-        import jax
-
-        from ...ops import bls12_381 as k
         from ..bls12_381 import (
             G1_GENERATOR, G2_GENERATOR, g1_compress, g2_compress,
         )
 
-        jit_type = type(k.final_exponentiation)     # a jax.jit wrapper
-        jitted = {}
-        for f in vars(k).values():
-            if isinstance(f, jit_type):
-                if jitted.setdefault(f.__name__, f) is not f:
-                    raise RuntimeError(f"two stage programs are named "
-                                       f"{f.__name__!r}")
-        # one real set, the generators' — every other lane is padding
+        # one real set, the generators', every other lane padding; then
+        # its signature with keys from the pubkey table (summed on the
+        # device, over two chunks) at the table's present shape
         dummy = parse_sets(self, [SignatureSet(
             g2_compress(G2_GENERATOR), [g1_compress(G1_GENERATOR)], b"")])
+        depth, buckets = key_shape()
+        on_table = ([None], *dummy[1:4],
+                    [np.zeros(depth * (buckets - 1) + 1, np.int32)])
         small, big = lane_options()
-        jobs = {}
-        for lanes in sorted({small, big}):
-            prep = host_prepare(*dummy, lanes, small)
-            arrays = {n: v for n, v in prep.items()
-                      if isinstance(v, np.ndarray)}
-            traced = jax.make_jaxpr(lambda a: list(
-                device_checks({**prep, **a}, lanes)))(arrays)
-            for eqn in traced.eqns:
-                if eqn.params.get("name") not in jitted:
-                    continue          # eager glue: tiny programs
-                args = tuple(jax.ShapeDtypeStruct(
-                    v.aval.shape, v.aval.dtype, weak_type=v.aval.weak_type)
-                    for v in eqn.invars)
-                name = eqn.params["name"]
-                key = (name, tuple((a.shape, a.dtype) for a in args))
-                jobs[key] = (name, jitted[name], args)
-        with ThreadPoolExecutor(max_workers=COMPILE_THREADS) as pool:
-            futures = [(name, pool.submit(
-                lambda fn, args: fn.lower(*args).compile(), fn, args))
-                for name, fn, args in jobs.values()]
-            return [(name, f.result()) for name, f in futures]
+        return compile_stage_programs(
+            [({**host_prepare(*parsed, lanes, small),
+               "pk_table": self.table.arrays()}, lanes)
+             for lanes, parsed in itertools.product(sorted({small, big}),
+                                                    (dummy, on_table))])
 
     def verify_signature_sets(self, sets: list[SignatureSet]) -> bool:
         if not sets:
@@ -388,26 +557,26 @@ class TpuBackend(PythonBackend):
         parsed = parse_sets(self, sets)
         if parsed is None:
             return False
-        pks, sig_xs, sig_flags, msgs = parsed
         small, big = lane_options()
         n = len(sets)
         for i in range(0, n, big):
             m = min(big, n - i)
             lanes = small if m <= small else big
-            if not self._verify_chunk(pks[i:i + m], sig_xs[i:i + m],
-                                      sig_flags[i:i + m],
-                                      msgs[i:i + m], lanes):
+            if not self._verify_chunk(*(part[i:i + m] for part in parsed),
+                                      lanes):
                 return False
         return True
 
-    def _verify_chunk(self, pks, sig_xs, sig_flags, msgs,
+    def _verify_chunk(self, pks, sig_xs, sig_flags, msgs, rows,
                       lanes: int) -> bool:
         """One fixed-shape device pass over m<=lanes real sets, padded to
         `lanes` with cached generator lanes (scalar 0, output masked);
         host prep + segment layout shared with the mesh-sharded
         verifier in `host_prepare`."""
-        prep = host_prepare(pks, sig_xs, sig_flags, msgs, lanes,
+        prep = host_prepare(pks, sig_xs, sig_flags, msgs, rows, lanes,
                             lane_options()[0])
+        if "agg_rows" in prep:
+            prep["pk_table"] = self.table.arrays()
         try:
             return all(bool(np.asarray(ok).all())
                        for ok in device_checks(prep, lanes))
@@ -415,20 +584,3 @@ class TpuBackend(PythonBackend):
             # the stages' outputs are read: their spans land at once
             tracing.wait_device_spans()
 
-
-def _encode_g1_batch(k, points):
-    xs, ys = [], []
-    for p in points:
-        x, y = p.to_affine()
-        xs.append(int(x))
-        ys.append(int(y))
-    return k.fp_encode(xs), k.fp_encode(ys)
-
-
-def _encode_g2_batch(k, points):
-    xs, ys = [], []
-    for p in points:
-        x, y = p.to_affine()
-        xs.append(x)
-        ys.append(y)
-    return k.fp2_encode(xs), k.fp2_encode(ys)

@@ -72,6 +72,8 @@ SPAN_KINDS: dict[str, str] = {
     "bls_parse": "bls_parse_seconds",
     "bls_prepare": "bls_prepare_seconds",
     "bls_scalars": "bls_scalars_seconds",
+    "bls_pk_table": "bls_pk_table_seconds",
+    "bls_pk_aggregate": "bls_device_pk_aggregate_seconds",
     "bls_decompress": "bls_device_decompress_seconds",
     "bls_subgroup": "bls_device_subgroup_seconds",
     "bls_hash_to_g2": "bls_device_hash_to_g2_seconds",

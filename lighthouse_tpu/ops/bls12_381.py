@@ -22,6 +22,7 @@ import numpy as np
 
 from ..crypto.bls12_381.fields import P as P_INT, X_PARAM
 from . import bigint as bi
+from .stages import freeze, stage
 
 # ---------------------------------------------------------------------------
 # host <-> device conversion
@@ -520,11 +521,11 @@ g2_scalar_mul.__name__ = g2_scalar_mul.__qualname__ = "g2_scalar_mul"
 
 # jitted entry points for the eager host pipeline (scan bodies compile
 # once; unjitted they dispatch op-by-op)
-g1_scalar_mul_jit = jax.jit(g1_scalar_mul)
-g2_scalar_mul_jit = jax.jit(g2_scalar_mul)
+g1_scalar_mul_jit = stage(g1_scalar_mul)
+g2_scalar_mul_jit = stage(g2_scalar_mul)
 
 
-@jax.jit
+@stage
 def g1_segment_sum(x, y, z, starts, ends):
     """Per-segment Jacobian G1 sums in log-depth steps.
 
@@ -560,21 +561,63 @@ def g1_segment_sum(x, y, z, starts, ends):
     return ox[ends], oy[ends], oz[ends]
 
 
-@jax.jit
+@stage
+def g1_table_gather(tx, ty, rows):
+    """Rows of the device pubkey table (``crypto/bls/pubkey_table.py``):
+    affine ``tx[rows]``, ``ty[rows]`` for a ``[depth, buckets]`` layout of
+    row indices."""
+    return tx[rows], ty[rows]
+
+
+@stage
+def g1_bucket_sum(x, y, live, starts, ends, multi):
+    """One chunk of the per-set pubkey sums.
+
+    ``x``, ``y`` [depth, buckets, 32] hold affine keys, ``live`` marks the
+    real ones (the rest count as the identity).  Each bucket holds up to
+    ``depth`` keys of one set: a scan over the depth sums every bucket
+    (depth steps, one addition per key lane), then a segmented scan over
+    the buckets (:func:`g1_segment_sum`, ``starts`` 1 at each set's first
+    bucket) gathers each set's sum at ``ends[lane]``, its last bucket
+    (an empty bucket for lanes with no keys in this chunk).  About
+    ``n + (n / depth) * log2(buckets)`` additions for ``n`` key lanes,
+    against ``n * log2(n)`` for one segmented scan over the keys.
+    Returns the sums per set lane and, per lane, False where a ``multi``
+    lane's sum is the identity."""
+    z = jnp.where(live[..., None], jnp.asarray(FP_ONE), 0)
+    zero = jnp.zeros_like(x[0])
+
+    def step(acc, key):
+        return g1_add(*acc, *key), None
+
+    (bx, by, bz), _ = jax.lax.scan(step, (zero, zero, zero), (x, y, z))
+    sx, sy, sz = g1_segment_sum(bx, by, bz, starts, ends)
+    return sx, sy, sz, ~(multi & _fp_is_zero(sz))
+
+
+@stage
+def g1_sum_merge(ax, ay, az, bx, by, bz, multi):
+    """Per-lane sums of two chunks' pubkey sums (a set crossing a chunk
+    boundary), with :func:`g1_bucket_sum`'s identity flags."""
+    x, y, z = g1_add(ax, ay, az, bx, by, bz)
+    return x, y, z, ~(multi & _fp_is_zero(z))
+
+
+@stage
 def jacobian_to_affine_fp2(x, y, z):
     zi = fp2_inv(z)
     zi2 = fp2_square(zi)
     return fp2_mul(x, zi2), fp2_mul(y, fp2_mul(zi2, zi))
 
 
-@jax.jit
+@stage
 def jacobian_to_affine_fp(x, y, z):
     zi = fp_inv(z)
     zi2 = fp_mul(zi, zi)
     return fp_mul(x, zi2), fp_mul(y, fp_mul(zi2, zi))
 
 
-@jax.jit
+@stage
 def _g2_sum_rows(x, y, z):
     """Row-wise jacobian sum via ONE scan: [m, w, 2, 32] -> [w, 2, 32].
     Body compiles once regardless of m — the compile-friendly shape for
@@ -675,7 +718,7 @@ def _ell(f, coeffs, px, py):
                            jnp.stack([a, b], axis=-2))
 
 
-@jax.jit
+@stage
 def miller_loop_batch(px, py, qx, qy):
     """f_i = miller(P_i, Q_i) for a batch of affine pairs.
 
@@ -711,7 +754,7 @@ def miller_loop_batch(px, py, qx, qy):
     return fp12_conj(f)
 
 
-@jax.jit
+@stage
 def _fp12_prod_rows(fs):
     """Row-wise product via ONE scan: [m, w, ...] -> [w, ...]."""
     init = fp12_one_like(fs.shape[1:2]) + (fs[0] & jnp.int32(0))
@@ -805,7 +848,7 @@ for _t in range(_HARD_NBITS):
                         for _i, d in enumerate(_HARD_DIGITS))
 
 
-@jax.jit
+@stage
 def final_exponentiation(f):
     """f^((p^12-1)/r) for a single Fp12 element [...]."""
     f = fp12_mul(fp12_conj(f), fp12_inv(f))       # easy: f^(p^6-1)
@@ -834,7 +877,7 @@ def final_exponentiation(f):
     return out
 
 
-@jax.jit
+@stage
 def _mask_to_one(fs, mask):
     """Replace masked-out Miller outputs with the Fp12 identity so padded
     lanes don't perturb the product (static-shape pipeline support)."""
@@ -1034,24 +1077,24 @@ def psi_g2(x, y, z):
 # jitted programs — each stays in the linear-compile regime, and the
 # inter-stage cost is one device round-trip of [n, 2, 32] arrays.
 
-@jax.jit
+@stage
 def _cc_mul_k1(x, y, z):
     return g2_scalar_mul_const(x, y, z, _BP_K1)
 
 
-@jax.jit
+@stage
 def _cc_mul_k2_psi(x, y, z):
     ux, uy, uz = g2_scalar_mul_const(x, y, z, _BP_K2)
     return psi_g2(ux, fp2_neg(uy), uz)
 
 
-@jax.jit
+@stage
 def _cc_dbl_psi2(x, y, z):
     dx, dy, dz = g2_dbl(x, y, z)
     return psi_g2(*psi_g2(dx, dy, dz))
 
 
-@jax.jit
+@stage
 def _g2_add3(x1, y1, z1, x2, y2, z2, x3, y3, z3):
     ax, ay, az = g2_add(x1, y1, z1, x2, y2, z2)
     return g2_add(ax, ay, az, x3, y3, z3)
@@ -1068,14 +1111,14 @@ def clear_cofactor_g2(x, y, z):
     return _g2_add3(*t1, *t2, *t3)
 
 
-@jax.jit
+@stage
 def map_to_g2_batch(u):
     """map_to_curve (SSWU + iso) for a [n, 2, 32] batch of field elements."""
     x, y = sswu_map_g2(u)
     return iso_map_g2(x, y)
 
 
-@jax.jit
+@stage
 def _g2_add_halves(x, y, z):
     """[2n,...] -> pairwise sum of the two halves [n,...]."""
     h = x.shape[0] // 2
@@ -1139,7 +1182,7 @@ def fp2_lex_larger(a):
     return jnp.where(c1_nz, _limbs_gt(c1, half), _limbs_gt(c0, half))
 
 
-@jax.jit
+@stage
 def g2_decompress_batch(x, want_larger):
     """Batched y-recovery for compressed G2 points.  x: [n, 2, 32] mont
     x-coords (host-parsed + range-checked), want_larger: [n] bool sign
@@ -1160,7 +1203,7 @@ def g2_eq_jac(x1, y1, z1, x2, y2, z2):
     return jnp.where(inf1 | inf2, inf1 & inf2, ex & ey)
 
 
-@jax.jit
+@stage
 def g2_in_subgroup_batch(x, y, z):
     """psi(Q) == [u]Q (u < 0): the 64-bit endomorphism subgroup check the
     C++ backend runtime-verifies against mul-by-r; cross-checked vs the
@@ -1178,3 +1221,8 @@ CONST_LADDERS = {
     "_cc_mul_k2_psi": (_BP_K2,),
     "miller_loop_batch": (_X_ABS,),
 }
+
+# the start-up store reads and writes stage programs only while the
+# functions they are traced from are these
+freeze(globals())
+freeze(vars(bi))

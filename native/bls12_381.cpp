@@ -417,7 +417,21 @@ static bool g2_on_curve(const G2&p){
     return fp2_eq(l,r);
 }
 static u8 R_BYTES_BE[32];
-static bool g1_in_subgroup(const G1&p){ G1 t; g1_mul(t,p,R_BYTES_BE,32); return g1_is_inf(t); }
+// G1 membership by the endomorphism sigma(x,y) = (beta x, y), with beta =
+// 2^((p-1)/3) mod p, the cube root of unity on which sigma acts on G1 as
+// [-u^2]: P is in G1 iff sigma(P) + [u^2]P = O (Bowe, "Faster subgroup
+// checks for BLS12-381"; u^4 - u^2 + 1 = r), a 128-bit ladder in place of
+// r's 255 bits.  bls_selftest fails if beta does not fix the generator.
+static const Fp G1_BETA_PLAIN={{0x2e01fffffffefffeULL,0xde17d813620a0002ULL,
+    0xddb3a93be6f89688ULL,0xba69c6076a0f77eaULL,0x5f19672fdf76ce51ULL,0}};
+static Fp G1_BETA;                 // Montgomery form, set at init
+static u8 U2_BYTES_BE[16];         // u^2, big-endian
+static bool g1_in_subgroup(const G1&p){
+    G1 s=p; fp_mul(s.x,p.x,G1_BETA);
+    G1 t,o; g1_mul(t,p,U2_BYTES_BE,16);
+    g1_add(o,s,t);
+    return g1_is_inf(o);
+}
 static bool g2_in_subgroup_slow(const G2&p){ G2 t; g2_mul(t,p,R_BYTES_BE,32); return g2_is_inf(t); }
 
 // psi endomorphism on the twist: psi(x,y) = (PSI_CX * conj(x), PSI_CY * conj(y))
@@ -1002,6 +1016,9 @@ static void ensure_init(){
         }
         USE_FAST_COFACTOR=cok;
     }
+    u128 u2=(u128)U_ABS*U_ABS;
+    for(int i=0;i<16;i++) U2_BYTES_BE[i]=(u8)(u2>>(120-8*i));
+    fp_to_mont(G1_BETA,G1_BETA_PLAIN);
 }
 
 // ---------------------------------------------------------------------------
@@ -1194,6 +1211,35 @@ int bls_validate_pubkey(const u8*pk48){
     if(!g1_decompress(p,pk48)) return 0;
     if(g1_is_inf(p)) return 0;
     return g1_in_subgroup(p)?1:0;
+}
+// bulk pubkey load (the tpu backend's device pubkey table): n compressed
+// keys -> affine x || y (48 bytes big-endian each) in out96, and ok[i] = 1
+// where key i passes KeyValidate (decodes, not the identity, in G1);
+// rows of failed keys are zero.  Split over `threads` threads.
+int bls_g1_decompress_batch(size_t n,const u8*pks,u8*out96,u8*ok,
+                            size_t threads){
+    ensure_init();
+    auto work=[&](size_t lo,size_t hi){
+        for(size_t i=lo;i<hi;i++){
+            G1 p; u8*o=out96+96*i;
+            ok[i]=0; memset(o,0,96);
+            if(!g1_decompress(p,pks+48*i)||g1_is_inf(p)
+               ||!g1_in_subgroup(p))
+                continue;
+            Fp t;                          // decompression leaves z = 1
+            fp_from_mont(t,p.x); fp_to_be(o,t);
+            fp_from_mont(t,p.y); fp_to_be(o+48,t);
+            ok[i]=1;
+        }
+    };
+    if(threads<1) threads=1;
+    size_t chunk=(n+threads-1)/threads;
+    if(threads==1||chunk<64){ work(0,n); return 0; }
+    std::vector<std::thread> th;
+    for(size_t lo=0;lo<n;lo+=chunk)
+        th.emplace_back(work,lo,lo+chunk<n?lo+chunk:n);
+    for(auto&x:th) x.join();
+    return 0;
 }
 // cross-check helpers: expose uncompressed affine coords of hash_to_g2
 int bls_hash_to_g2_affine(const u8*msg,size_t msglen,const u8*dst,size_t dstlen,

@@ -6,6 +6,8 @@
   never overlapping; a failed first check stops at ``bls_decompress``;
   ``make_jaxpr`` over the device half records nothing; a verify feeds
   the constant-ladder counters with each program's static counts.
+- A node built on ``tpu`` compiles its stage programs after loading its
+  registry's keys: its first multi-key verify compiles none of them.
 - Block import: the four new kinds nest in ``block_import``.
 - Profiler clock: a host span holds a ``lighthouse_tpu:<kind>``
   annotation of the same length in a JAX profile.
@@ -36,6 +38,7 @@ IMPORT_STAGES = ["pre_state", "signature_sets", "post_import",
 def stand_ins(monkeypatch):
     """The stage kernels of ``ops.bls12_381`` as shape-true stand-ins;
     ``stand_ins["on_curve"]`` sets what decompression reports."""
+    import jax
     import jax.numpy as jnp
 
     from lighthouse_tpu.ops import bls12_381 as k
@@ -59,15 +62,19 @@ def stand_ins(monkeypatch):
                                    jnp.asarray(z)),
         "g2_scalar_mul_jit": lambda x, y, z, bits: (x, y, z),
         "g1_segment_sum":
-            lambda x, y, z, starts, ends: (x[:len(ends)], y[:len(ends)],
-                                           z[:len(ends)]),
+            lambda x, y, z, starts, ends: (x[jnp.asarray(ends)],
+                                           y[jnp.asarray(ends)],
+                                           z[jnp.asarray(ends)]),
         "g2_sum": lambda x, y, z: (x[0], y[0], z[0]),
         "pairing_check_batch":
             lambda px, py, qx, qy, mask=None: jnp.any(jnp.asarray(mask)),
     }
     for name, fn in stubs.items():
         monkeypatch.setattr(k, name, fn)
-    return state
+    yield state
+    # a real kernel traced meanwhile called the stand-ins; JAX's caches
+    # would hand that program to later tests of this process
+    jax.clear_caches()
 
 
 @pytest.fixture
@@ -166,6 +173,100 @@ def test_verify_counts_constant_ladder_steps_and_additions(stand_ins, tpu):
     assert [k.ladder_counts(c) for c in constants] == [
         (63, 5), (127, 37), (63, 6), (63, 5)]
     assert (steps, adds) == (63 + 127 + 63 + 63, 5 + 37 + 6 + 5)
+
+
+def test_multi_key_sets_add_the_pubkey_sum_stage_first(stand_ins, tpu,
+                                                       monkeypatch):
+    """A set of several keys puts the ``bls_pk_aggregate`` device stage
+    (table gather, bucket sums) before decompression and feeds the
+    aggregation counters: keys summed, key lanes left empty."""
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.api import metrics
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.crypto.bls import SignatureSet
+    from lighthouse_tpu.crypto.bls import tpu_backend as tb
+    from lighthouse_tpu.crypto.bls12_381 import G1_GENERATOR, g1_compress
+    from lighthouse_tpu.ops import bls12_381 as k
+    monkeypatch.setattr(
+        k, "g1_bucket_sum",
+        lambda x, y, live, starts, ends, multi:
+        (x[0, :len(multi)], y[0, :len(multi)], x[0, :len(multi)],
+         jnp.ones(len(multi), bool)))
+    names = ("bls_pubkeys_aggregated_total", "bls_key_lanes_padded_total")
+    before = [metrics.counter_value(name) for name in names]
+    sets = _sets()
+    sets.append(SignatureSet(sets[0].signature, [
+        g1_compress(G1_GENERATOR.mul(m)) for m in (2, 3, 4)], b"c"))
+    tracing.clear()
+    assert bls.verify_signature_sets(sets) is True
+    device = [s.kind for s in tracing.snapshot()
+              if s.kind in DEVICE_STAGES + ["bls_pk_aggregate"]]
+    assert device == ["bls_pk_aggregate"] + DEVICE_STAGES
+    depth, buckets = tb.key_shape()
+    keys, padded = (metrics.counter_value(name) - was
+                    for name, was in zip(names, before))
+    # every set's keys are summed once one set has several: the three
+    # generator sets' and the multi-key set's, four distinct keys
+    assert (keys, padded) == (6, depth * buckets - 6)
+    assert tpu.table.size == 4
+
+
+def test_a_node_compiles_no_stage_program_on_its_first_multi_key_verify(
+        stand_ins, monkeypatch):
+    """``ClientBuilder`` fills the pubkey table before it compiles the
+    stage programs, so the table gather is compiled at the loaded shape
+    (a registry past the first block of rows grows the table)."""
+    import threading
+    import time
+
+    import jax.monitoring
+
+    from lighthouse_tpu.client.builder import ClientBuilder, ClientConfig
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.crypto.bls import SignatureSet
+    from lighthouse_tpu.crypto.bls import pubkey_table
+    from lighthouse_tpu.ops import bls12_381 as k
+    from lighthouse_tpu.specs import minimal_spec
+
+    from lighthouse_tpu.state_transition import interop_genesis_state
+
+    spec = minimal_spec(altair_fork_epoch=0)
+    count = pubkey_table.block_rows() + 8
+    monkeypatch.setattr(bls, "_current", None)
+    bls.set_backend("cpp")              # the genesis keys, natively
+    genesis = interop_genesis_state(       # slot 0 now: no catching up
+        spec, [bls.keygen_interop(i) for i in range(count)],
+        genesis_time=int(time.time()))
+    client = ClientBuilder(spec).with_config(ClientConfig(
+        crypto_backend="tpu", http_enabled=False,
+        genesis_state=genesis)).build()
+    compiled, verifying = [], []         # names as "jit(<function>)"
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, fun_name="", **kw: compiled.append(
+            fun_name.removeprefix("jit(").removesuffix(")"))
+        if verifying and event == "/jax/core/compile/backend_compile_duration"
+        else None)
+    try:
+        backend = bls.get_backend()
+        assert backend.table.size == count
+        keys = [bytes(pk) for pk in
+                client.chain.head().head_state.validators.pubkeys[-3:]]
+        sig = bls.PythonBackend().sign(1, b"m")
+        verifying.append(True)
+        bls.verify_signature_sets([SignatureSet(sig, keys, b"m")])
+        verifying.clear()
+    finally:
+        client.env.shutdown("test over")      # the per-slot timer ends
+        client.stop()
+        client.processor.stop()
+        for t in threading.enumerate():
+            if t.name == "timer":
+                t.join(10)
+    stages = {name for name, f in vars(k).items()
+              if isinstance(f, type(k.final_exponentiation))}
+    assert "g1_table_gather" in stages
+    assert not stages & set(compiled), compiled
 
 
 def test_device_span_of_a_failed_stage_still_ends(monkeypatch):

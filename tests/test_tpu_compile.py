@@ -106,3 +106,29 @@ def test_bls_subgroup_check_branches_on_the_bit_128_lanes(one_chip):
                 if " conditional(" in line]
     assert len(branches) == 1, branches
     assert "/while/body/" in branches[0]
+
+
+def test_bls_pubkey_table_gather_and_write_2_20_rows(one_chip):
+    """The device pubkey table at 2^20 validators (268 MB): one block's
+    key lanes gathered from it, one block of rows written to it."""
+    from lighthouse_tpu.crypto.bls.pubkey_table import _write_block
+    table = jax.ShapeDtypeStruct((1 << DEPTH, bi.NLIMBS), jnp.int32,
+                                 sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((16, 2304), jnp.int32, sharding=one_chip)
+    m = _compile_fits(k.g1_table_gather, table, table, rows)
+    assert m.argument_size_in_bytes >= 2 * (1 << DEPTH) * bi.NLIMBS * 4
+    block = jax.ShapeDtypeStruct((65536, bi.NLIMBS), jnp.int32,
+                                 sharding=one_chip)
+    _compile_fits(_write_block, table, table, block, block,
+                  jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+
+def test_bls_bucket_sum_one_block_of_keys(one_chip):
+    """The per-set pubkey sums of a full block at 2^20 validators: 16 x
+    2,304 key lanes onto 128 set lanes (``tpu_backend.key_shape``)."""
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    keys = shape(16, 2304, bi.NLIMBS)
+    _compile_fits(k.g1_bucket_sum, keys, keys,
+                  shape(16, 2304, dtype=jnp.bool_), shape(2304),
+                  shape(128), shape(128, dtype=jnp.bool_))
