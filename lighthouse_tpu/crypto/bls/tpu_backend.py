@@ -316,7 +316,9 @@ def device_checks(prep: dict, lanes: int):
     """The device half of one chunk, prepared by :func:`host_prepare`:
     yields, in order, the signatures' on-curve flags, their subgroup
     flags, where a set has several keys the flags of :func:`pubkey_sums`
-    (no sum is the identity), and the batch pairing verdict.  A
+    (no sum is the identity), and the batch pairing verdict, which the
+    host dispatches as two programs, ``miller_loop_batch`` and
+    ``pairing_verdict``, with no eager operation between them.  A
     generator, so ``_verify_chunk`` stops at the first failed check and
     ``TpuBackend.precompile`` traces them all to find the programs.
 
@@ -406,7 +408,7 @@ def device_checks(prep: dict, lanes: int):
         jnp.concatenate([msg_x, aax[None]], axis=0),
         jnp.concatenate([msg_y, aay[None]], axis=0)))
     stage = tracing.device_span("bls_pairing")
-    verdict = k.pairing_check_batch(px, py, qx, qy, mask=prep["mask"])
+    verdict = k.pairing_check_batch(px, py, qx, qy, prep["mask"])
     count_ladders("miller_loop_batch")
     yield stage.watch(verdict)
 

@@ -768,7 +768,7 @@ def _fp12_prod_rows(fs):
 
 def fp12_product(fs, width: int = 64):
     """Product over the batch axis: pad with ones to a multiple of
-    `width`, scan the rows, scan the partials (two cached programs —
+    `width`, scan the rows, scan the partials (two scans —
     compile-friendly for any batch size)."""
     n = fs.shape[0]
     w = min(width, max(1, n))
@@ -877,28 +877,37 @@ def final_exponentiation(f):
     return out
 
 
-@stage
-def _mask_to_one(fs, mask):
+def mask_to_one(fs, mask):
     """Replace masked-out Miller outputs with the Fp12 identity so padded
     lanes don't perturb the product (static-shape pipeline support)."""
     one = fp12_one_like((fs.shape[0],))
     return jnp.where(mask[:, None, None, None, None], fs, one)
 
 
+@stage
+def pairing_verdict(fs, mask):
+    """prod_i fs_i over the lanes ``mask`` selects (bool [n]), raised to
+    the final exponent, == 1: the masked product, the final
+    exponentiation and the canonical comparison in one program, so the
+    host dispatches it and nothing else after the Miller loop."""
+    out = final_exponentiation(fp12_product(mask_to_one(fs, mask)))
+    return fp12_eq(out, fp12_one_like(()))
+
+
 def pairing_check_batch(px, py, qx, qy, mask=None) -> jax.Array:
-    """prod_i e(P_i, Q_i) == 1 (one shared final exponentiation).
+    """prod_i e(P_i, Q_i) == 1 (one shared final exponentiation), as two
+    programs: ``miller_loop_batch`` and ``pairing_verdict``.
 
     ``mask`` (bool [n], optional) selects the lanes that participate in
     the product — padding lanes of a fixed-shape batch pass False and
     contribute the identity, so ONE compiled program serves every batch
     size up to n (the per-batch-shape recompiles were VERDICT r3 weak #2).
+    ``None`` selects every lane.
     """
-    fs = miller_loop_batch(px, py, qx, qy)
-    if mask is not None:
-        fs = _mask_to_one(fs, jnp.asarray(mask))
-    prod = fp12_product(fs)
-    out = final_exponentiation(prod)
-    return fp12_eq(out[None], fp12_one_like((1,)))[0]
+    if mask is None:
+        mask = np.ones(px.shape[0], bool)
+    # positional: a stage runs its registered executable only so
+    return pairing_verdict(miller_loop_batch(px, py, qx, qy), mask)
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
@@ -23,11 +24,10 @@ from ..obs import device
 from ..obs.jax_accounting import host_readback
 from ..obs.roofline import track_roofline
 from ..ops.bls12_381 import (
-    final_exponentiation,
-    fp12_eq,
-    fp12_one_like,
     fp12_product,
+    mask_to_one,
     miller_loop_batch,
+    pairing_verdict,
 )
 
 
@@ -40,11 +40,15 @@ def _local_miller_product(px, py, qx, qy):
 
 
 def _local_masked_product(lpx, lpy, lqx, lqy, lmask):
-    import jax.numpy as jnp_
     fs = miller_loop_batch(lpx, lpy, lqx, lqy)
-    one = fp12_one_like((fs.shape[0],))
-    fs = jnp_.where(lmask[:, None, None, None, None], fs, one)
-    return fp12_product(fs)[None]
+    return fp12_product(mask_to_one(fs, lmask))[None]
+
+
+def _verdict(partials):
+    """The shared tail: the chips' partial products into one
+    ``pairing_verdict`` program (their product, the final
+    exponentiation and the comparison with one)."""
+    return pairing_verdict(partials, np.ones(partials.shape[0], bool))
 
 
 # Memoized jitted programs per (mesh, axis): a fresh jit(shard_map(...))
@@ -91,18 +95,18 @@ def sharded_pairing_check(mesh: Mesh, px, py, qx, qy,
     """prod_i e(P_i, Q_i) == 1 with the pair batch row-sharded over the
     mesh.  The batch size must divide evenly across mesh[axis].
 
-    STAGED (compile-regime discipline, ops/bls12_381.py): stage 1 is the
-    sharded Miller loop + per-chip local product — its out_spec gathers
-    the n_dev partials over ICI (n_dev * 1.5 KiB, one tiny collective);
-    stage 2 (tiny product + the shared final exponentiation + identity
-    check) runs as separate cached programs on the gathered result.  One
-    fused program here was the round-2 ~12-minute compile."""
+    STAGED (compile-regime discipline, ops/bls12_381.py): two programs.
+    The first is the sharded Miller loop + per-chip local product — its
+    out_spec gathers the n_dev partials over ICI (n_dev * 1.5 KiB, one
+    tiny collective); the second, ``pairing_verdict``, takes the tiny
+    product, the shared final exponentiation and the identity check on
+    the gathered result.  One fused program here was the round-2
+    ~12-minute compile."""
     with device.hbm_watermark("parallel.bls"):
         device.attribute("parallel.bls", "pairing_inputs", px, py, qx, qy)
         partials = _miller_product_fn(mesh, axis)(px, py, qx,
                                                   qy)  # [n_dev,2,3,2,32]
-        out = final_exponentiation(fp12_product(partials))
-        return fp12_eq(out[None], fp12_one_like((1,)))[0]
+        return _verdict(partials)
 
 
 def sharded_verify_signature_sets(mesh: Mesh, sets, lanes: int,
@@ -117,14 +121,13 @@ def sharded_verify_signature_sets(mesh: Mesh, sets, lanes: int,
     RLC scalar multiplications SHARDED over the mesh, the scaled-signature
     sum via per-shard partial sums gathered over ICI, the segmented
     per-message pubkey sums on the gathered scaled points, and the
-    sharded Miller loop + one replicated final exponentiation.
+    pairing check as two programs: the sharded Miller loop with each
+    chip's masked product, then one replicated ``pairing_verdict``.
 
     `lanes` must be a multiple of mesh[axis].  Returns the verification
     bool; semantics are cross-checked against the single-device
     `TpuBackend` in the driver dryrun and tests/test_parallel.py.
     """
-    import numpy as np
-
     import lighthouse_tpu.ops.bls12_381 as k
     from lighthouse_tpu.ops import bigint as bi
     from lighthouse_tpu.crypto.bls.tpu_backend import (
@@ -226,5 +229,5 @@ def sharded_verify_signature_sets(mesh: Mesh, sets, lanes: int,
         device.attribute("parallel.bls", "miller_pairs", px, py, qx, qy)
         partials = _masked_product_fn(mesh, axis)(px, py, qx, qy,
                                                   jnp.asarray(full_mask))
-        out = final_exponentiation(fp12_product(partials))
-    return bool(host_readback(fp12_eq(out[None], fp12_one_like((1,)))[0]))
+        verdict = _verdict(partials)
+    return bool(host_readback(verdict))

@@ -270,20 +270,54 @@ def test_final_exp_matches_python():
     assert k.fp_decode(out) == _f12_to_ints(want)
 
 
-def test_pairing_check_verifies_signature():
+def _check_pairs(name):
+    """Two pairs: a signature's check, the same with a wrong message, or
+    junk (unrelated points)."""
+    if name == "junk":
+        pairs = [(G1_GENERATOR.mul(3), G2_GENERATOR.mul(5)),
+                 (G1_GENERATOR.mul(2), G2_GENERATOR.mul(9))]
+        return (*_encode_g1([p for p, _ in pairs]),
+                *_encode_g2([q for _, q in pairs]))
     sk = keygen_interop(3)
-    pk = sk_to_pk(sk)
-    msg = b"\x5a" * 32
-    sig = sign(sk, msg)
-    h = hash_to_g2(msg)
+    msg = b"\x5a" * 32 if name == "signature" else b"\x5b" * 32
     # e(-g1, sig) * e(pk, h) == 1
-    px, py = _encode_g1([G1_GENERATOR.neg(), pk])
-    qx, qy = _encode_g2([sig, h])
-    assert bool(np.asarray(k.pairing_check_batch(px, py, qx, qy)))
-    # wrong message fails
-    h2 = hash_to_g2(b"\x5b" * 32)
-    qx2, qy2 = _encode_g2([sig, h2])
-    assert not bool(np.asarray(k.pairing_check_batch(px, py, qx2, qy2)))
+    px, py = _encode_g1([G1_GENERATOR.neg(), sk_to_pk(sk)])
+    qx, qy = _encode_g2([sign(sk, b"\x5a" * 32), hash_to_g2(msg)])
+    return px, py, qx, qy
+
+
+@pytest.mark.parametrize("pairs,mask,want", [
+    ("signature", None, True),
+    ("signature", [True, True], True),      # all-true: as mask=None
+    ("wrong_message", None, False),
+    ("junk", None, False),
+    ("junk", [True, True], False),
+    ("junk", [False, False], True),         # masked out: the identity
+], ids=["signature", "signature-all_lanes", "wrong_message", "junk",
+        "junk-all_lanes", "junk-masked_out"])
+def test_pairing_check_verifies_signature(pairs, mask, want):
+    """Every case at two pairs: one Miller loop and one verdict program
+    serve them all."""
+    mask = None if mask is None else np.array(mask)
+    assert bool(np.asarray(k.pairing_check_batch(*_check_pairs(pairs),
+                                                 mask))) is want
+
+
+@pytest.mark.parametrize("mask", [None, "lanes"])
+def test_pairing_check_dispatches_two_programs_and_nothing_eager(mask):
+    """At the CPU batches' pairing shape (the message lanes and the
+    aggregate's) a check is two stage programs, the Miller loop and the
+    verdict, with no eager operation: each top-level equation of its
+    trace is one host dispatch."""
+    from lighthouse_tpu.crypto.bls.tpu_backend import lane_options
+    n = lane_options()[0] + 1
+    fp = jax.ShapeDtypeStruct((n, bi.NLIMBS), np.int32)
+    fp2 = jax.ShapeDtypeStruct((n, 2, bi.NLIMBS), np.int32)
+    lanes = None if mask is None else np.arange(n) % 2 == 0
+    jaxpr = jax.make_jaxpr(lambda *a: k.pairing_check_batch(*a, lanes))(
+        fp, fp, fp2, fp2)
+    assert [(e.primitive.name, e.params.get("name")) for e in jaxpr.eqns] \
+        == [("jit", "miller_loop_batch"), ("jit", "pairing_verdict")]
 
 
 def test_device_g2_decompress_and_subgroup():
