@@ -260,11 +260,8 @@ CATALOG: dict[str, tuple[str, str]] = {
         ("hist", "Gossip arrival -> imported, whole pipeline trace"),
     "beacon_processor_work_seconds":
         ("hist", "Beacon-processor work item execution latency"),
-    "bench_stage_seconds":
-        ("hist", "bench.py --trace per-stage latency"),
     "stf_epoch_seconds":
-        ("hist", "per_epoch_processing wall time (epoch boundary in the "
-                 "node, 1M-validator envelope in bench.py stf mode)"),
+        ("hist", "per_epoch_processing wall time at an epoch boundary"),
     "stf_block_seconds":
         ("hist", "per_block_processing wall time for one imported block"),
     # -- API serving tier (api/serving/, ISSUE 12) ------------------------

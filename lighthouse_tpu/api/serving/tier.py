@@ -47,8 +47,8 @@ class ServingTier:
         self.cache = ResponseCache(cache_capacity)
         self.coalescer = Coalescer()
         self.queue = AdmissionQueue(queue_workers, queue_capacity)
-        #: head key used when the backend has no live chain (bench
-        #: harness, tests); writable so tests can simulate head moves
+        #: head key used when the backend has no live chain (tests);
+        #: writable so tests can simulate head moves
         self.static_head_root = b"\x00" * 32
         self.requests = 0
         self._lock = threading.Lock()

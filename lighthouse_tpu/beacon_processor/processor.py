@@ -175,13 +175,20 @@ class BeaconProcessor:
         # re-attach the submitter's trace so the queue hop doesn't break
         # the block's gossip->db-write trace; batches adopt the first
         # item's context (they are one fused device call anyway)
-        with tracing.attach(first.trace_ctx), \
-                tracing.span("processor_work", work_kind=first.kind.name,
-                             batch=batch) as s:
-            if first.enqueued_at:
-                s.annotate(queue_wait_s=round(
-                    max(0.0, s.start - first.enqueued_at), 9))
-            self._execute_inner(work)
+        try:
+            with tracing.attach(first.trace_ctx), \
+                    tracing.span("processor_work",
+                                 work_kind=first.kind.name,
+                                 batch=batch) as s:
+                if first.enqueued_at:
+                    s.annotate(queue_wait_s=round(
+                        max(0.0, s.start - first.enqueued_at), 9))
+                self._execute_inner(work)
+        finally:
+            # idle only once the work's span is in the ring: wait_idle()
+            # then returns with every span of the work recorded
+            self._idle.release()
+            self._event.set()
 
     def _execute_inner(self, work) -> None:
         try:
@@ -224,9 +231,6 @@ class BeaconProcessor:
             import logging
             logging.getLogger("lighthouse_tpu.processor").exception(
                 "work item failed")
-        finally:
-            self._idle.release()
-            self._event.set()
 
     def wait_idle(self, timeout: float = 10.0) -> bool:
         """Test helper: block until all queues drained and workers idle."""

@@ -42,7 +42,7 @@ CHUNK_ROWS = 4096
 OVERLAY_MAX_LEAVES = 2048
 
 #: process-wide CoW accounting, mirrored into graftscope counters when
-#: the metrics module is loaded (bench.py fork_fanout reads the deltas).
+#: the metrics module is loaded.
 STATS = {"chunks_materialized": 0, "chunks_shared": 0, "rebases": 0,
          "bytes_materialized": 0, "bytes_shared": 0}
 

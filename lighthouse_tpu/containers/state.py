@@ -126,7 +126,7 @@ class ValidatorRegistry:
         # device-resident incremental merkle tree (ops/merkle_tree): None =
         # rebuild everything; _dirty_rows tracks which validator rows need
         # re-encoding + a dirty-path rehash (milhouse-style O(diff) root)
-        self._device_leaves = None   # legacy slot, kept for test/bench resets
+        self._device_leaves = None   # legacy slot, kept for test resets
         self._device_tree = None
         self._dirty_rows: set[int] | None = None
         # host-native twin (SHA-NI path when no accelerator is attached):
@@ -465,7 +465,7 @@ class BalancesColumn:
         self.dtype = np.dtype(dtype)
         self.per_chunk = 32 // self.dtype.itemsize
         self.values = np.ascontiguousarray(values, dtype=self.dtype)
-        self._device_leaves = None   # legacy slot, kept for test/bench resets
+        self._device_leaves = None   # legacy slot, kept for test resets
         self._device_tree = None
         self._host_tree = None
         self._host_shared = False

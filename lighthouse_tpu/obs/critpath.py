@@ -16,8 +16,8 @@ synthetic-DAG golden test pins the output shape.
 This is the number ROADMAP item 4 (pipelined import) needs: overlap
 headroom is exactly the critical path's self-time that a stage pipeline
 could hide.  Consumers: ``tools/trace/report.py --critpath``,
-``tools/obs/diff.py``, the flight recorder (worst trace of an incident
-window) and ``bench.py`` (PERF_MODEL §12).
+``tools/obs/diff.py`` and the flight recorder (worst trace of an
+incident window).
 """
 from __future__ import annotations
 

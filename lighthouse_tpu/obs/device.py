@@ -1,10 +1,9 @@
 """graftgauge device/HBM memory ledger (ISSUE 17).
 
-Every BENCH record through r06 says ``bls_platform: "cpu"`` — the stack
-could time anything but could not say what device ran it or how much
-HBM it used.  This module is the missing instrument: a per-device
-snapshot (platform, chip count, ``memory_stats()`` HBM bytes where the
-runtime exposes them, host RSS + CoW chunk accounting) sampled once per
+A timing means little without the device that ran it and the HBM it
+used.  This module is that instrument: a per-device snapshot (platform,
+chip count, ``memory_stats()`` HBM bytes where the runtime exposes
+them, host RSS + CoW chunk accounting) sampled once per
 slot into the graftwatch rings, an attribution registry tagging device
 arrays by owning subsystem, and an :func:`hbm_watermark` scope that
 stamps HBM high-water deltas onto the enclosing graftscope span.
@@ -14,7 +13,7 @@ the XLA CPU backend returns ``memory_stats() = None`` — every surface
 says ``"unavailable"`` explicitly instead of guessing, and the
 ``hbm_headroom`` SLO reads as unevaluable-not-breached.  jax is only
 looked at through ``sys.modules``: a process that never initialized a
-backend (lint rigs, the bench parent) never pays backend init for a
+backend (lint rigs, the doctor's probe) never pays backend init for a
 ledger read.
 """
 from __future__ import annotations
@@ -275,26 +274,23 @@ def flight_section() -> dict:
     return out
 
 
-# -- staged device-health probe (promoted from bench.py) ----------------------
+# -- staged device-health probe ----------------------------------------------
 
 _PROBE_STAGES = [("import", "import jax"),
                  ("devices", "import jax; jax.devices()")]
 
 
-def staged_probe(timeout: int = 90, env: dict | None = None,
-                 cwd: str | None = None) -> dict:
+def staged_probe(timeout: int = 90, cwd: str | None = None) -> dict:
     """Staged accelerator-acquisition probe: how far does JAX get on
     this host, under default init and under ``JAX_PLATFORMS=tpu``?
     Each stage is its own subprocess with a hard timeout, so a wedged
     libtpu acquisition can't hang the caller — the record says exactly
-    which stage died and how long it took.  ``bench.py`` feeds its
-    child env; ``tools/obs/doctor.py --probe`` runs it standalone."""
-    base = dict(os.environ if env is None else env)
+    which stage died and how long it took.  ``tools/obs/doctor.py
+    --probe`` runs it."""
     out: dict = {"timeout_s": timeout}
     for label, extra in (("default", {}),
                          ("forced_tpu", {"JAX_PLATFORMS": "tpu"})):
-        stage_env = dict(base)
-        stage_env.update(extra)
+        stage_env = {**os.environ, **extra}
         stage_reached = None
         stages = {}
         for stage, code in _PROBE_STAGES:

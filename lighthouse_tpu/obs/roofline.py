@@ -22,9 +22,6 @@ compile-budget rule flags factories that bypass it).  It extends
 - where AOT lowering is impossible (exotic call signatures) the program
   falls back to the plain :class:`~.jax_accounting.TrackedJit` path and
   its roofline record says ``cost: "unavailable"``.
-
-:func:`measure` is the one-shot variant bench.py uses for the
-per-kernel ``device`` block entries.
 """
 from __future__ import annotations
 
@@ -209,7 +206,6 @@ class RooflineJit:
 
 _lock = threading.Lock()
 _REGISTRY: dict[str, RooflineJit] = {}
-_MEASURED: dict[str, dict] = {}
 
 
 def track_roofline(name: str, fn) -> RooflineJit:
@@ -222,35 +218,14 @@ def track_roofline(name: str, fn) -> RooflineJit:
     return rj
 
 
-def measure(name: str, fn, *args, reps: int = 3, **kwargs) -> dict:
-    """One-shot roofline measurement of a jitted callable: AOT compile
-    (once), ``cost_analysis()``, then ``reps`` barriered timed runs.
-    Registers the record under ``name`` (bench.py's per-kernel device
-    block reads it back via :func:`snapshot`)."""
-    rj = RooflineJit(name, fn)
-    for _ in range(min(reps, SAMPLE_CALLS)):
-        rj(*args, **kwargs)
-    recs = rj.records()
-    rec = recs[0] if recs else {"cost": "unavailable", "calls": 0}
-    rec["kernel"] = name
-    with _lock:
-        _MEASURED[name] = rec
-    return rec
-
-
 def snapshot() -> dict:
     """{program name: [per-signature roofline records]} over every
-    tracked program, plus one-shot :func:`measure` results."""
+    tracked program."""
     with _lock:
         wrappers = dict(_REGISTRY)
-        measured = {k: dict(v) for k, v in _MEASURED.items()}
-    out: dict = {name: rj.records() for name, rj in wrappers.items()}
-    for name, rec in measured.items():
-        out.setdefault(name, []).append(rec)
-    return out
+    return {name: rj.records() for name, rj in wrappers.items()}
 
 
 def reset() -> None:
     with _lock:
         _REGISTRY.clear()
-        _MEASURED.clear()

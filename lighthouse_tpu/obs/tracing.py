@@ -87,9 +87,7 @@ SPAN_KINDS: dict[str, str] = {
     "cold_state_replay": "store_cold_state_replay_seconds",
     "el_new_payload": "execution_layer_new_payload_seconds",
     "el_forkchoice": "execution_layer_forkchoice_seconds",
-    # bench harness stages (bench.py --trace)
-    "bench_stage": "bench_stage_seconds",
-    # mainnet-envelope STF (slot.py epoch boundary, bench.py stf mode)
+    # state transition (slot.py epoch boundary, block_verification.py)
     "stf_epoch": "stf_epoch_seconds",
     "stf_block": "stf_block_seconds",
     # Beacon-API serving tier (api/serving/tier.py, ISSUE 12)
@@ -500,6 +498,9 @@ def _watch_device_spans(q: queue.SimpleQueue) -> None:
     import jax
     last_ready = 0.0
     while (item := q.get()) is not None:
+        if isinstance(item, threading.Event):   # a drain: all before it
+            item.set()                          # are recorded
+            continue
         stage, outputs = item
         try:
             error = None
@@ -537,7 +538,22 @@ def snapshot() -> list[Span]:
     return _ring.snapshot()
 
 
+def _drain_device_watcher() -> None:
+    """Wait until the watcher has recorded every stage watched so far."""
+    with _watcher_lock:
+        if _watcher is None:
+            return
+        q = _watcher[1]
+    drained = threading.Event()
+    q.put(drained)
+    drained.wait()
+
+
 def clear() -> None:
+    """Empty the ring.  The device spans of stages watched before the
+    call are recorded first, so none of them lands in the ring after it
+    (a test that clears, then reads, sees only its own)."""
+    _drain_device_watcher()
     _ring.clear()
 
 

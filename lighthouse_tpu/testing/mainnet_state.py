@@ -1,7 +1,7 @@
 """Mainnet-scale Altair states and blocks, built column-wise.
 
-Per-deposit genesis is O(n) Python loops, so the 1M-validator states the
-bench and ``chip_smoke.py`` drive are built straight into the SoA
+Per-deposit genesis is O(n) Python loops, so mainnet-sized states (the
+epoch-processing and block-import tests) are built straight into the SoA
 columns.  Pubkeys are random bytes (not curve points): blocks built here
 carry structurally valid signatures for the ``fake`` BLS backend only.
 """

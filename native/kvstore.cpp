@@ -62,6 +62,9 @@ bool parse_batch(const uint8_t* p, size_t n, std::vector<BatchOp>* out) {
   if (n < 4) return false;
   uint32_t count;
   memcpy(&count, p, 4);
+  // every op carries an 8-byte header: a count the payload cannot hold
+  // is rejected before it sizes the reserve
+  if (count > (n - 4) / 8) return false;
   size_t cur = 4;
   out->clear();
   out->reserve(count);

@@ -4,7 +4,7 @@ Tests run on a virtual 8-device CPU mesh so multi-chip sharding logic
 (jax.sharding.Mesh / shard_map) is exercised without TPU hardware, mirroring how
 the reference tests multi-node behavior in one process
 (/root/reference/testing/simulator/src/local_network.rs:107).
-Benchmarks (bench.py) and chip_smoke.py run on the TPU chip instead.
+The benchmark (benchmark/run.py) runs on the TPU chip instead.
 """
 import os
 import sys

@@ -1,12 +1,10 @@
 """graftwatch: slot sampler rings, SLO incident lifecycle, flight dump
-round-trip, the doctor golden file, the SLO->CATALOG cross-check, and
-the bench.py --against comparator."""
+round-trip, the doctor golden file and the SLO->CATALOG cross-check."""
 import json
 import os
 
 import pytest
 
-import bench
 from lighthouse_tpu import obs
 from lighthouse_tpu.api.metrics_defs import CATALOG
 from lighthouse_tpu.obs import doctor, flight, graftwatch, slo, timeseries
@@ -331,57 +329,3 @@ def test_doctor_rejects_garbage_and_wrong_version(tmp_path):
         doctor.load(str(p2))
     assert ei.value.exit_code == 3
 
-
-# -- bench --against comparator ----------------------------------------------
-
-
-def _bench_record(**over):
-    rec = {
-        "metric": "beacon_state_tree_hash_1m_validators",
-        "value": 10.0, "platform": "cpu",
-        "bls_sigs_per_sec": 100.0, "bls_platform": "cpu",
-        "epoch_ms_1m": 300.0,
-        "block_import_ms_1m": {"signatures_off": 2000.0},
-        "state_copy_ms": 1.0,
-        "mxu_mode_speedup": 2.0, "mxu_platform": "cpu",
-    }
-    rec.update(over)
-    return rec
-
-
-def test_bench_comparator_passes_improvement_and_noise():
-    old = _bench_record()
-    new = _bench_record(value=8.0,            # faster: improvement
-                        epoch_ms_1m=330.0)    # +10%: inside the limit
-    rep = bench.compare_records(old, new)
-    assert rep["ok"] and rep["regressions"] == []
-    status = {c["metric"]: c["status"] for c in rep["compared"]}
-    assert status["value"] == "improvement"
-    assert status["epoch_ms_1m"] == "within_limit"
-
-
-def test_bench_comparator_fails_regressions_both_directions():
-    old = _bench_record()
-    new = _bench_record(epoch_ms_1m=300.0 * 1.3,      # lower-is-better
-                        bls_sigs_per_sec=100.0 / 1.3)  # higher-is-better
-    rep = bench.compare_records(old, new)
-    assert not rep["ok"]
-    assert set(rep["regressions"]) == {"epoch_ms_1m",
-                                       "bls_sigs_per_sec"}
-
-
-def test_bench_comparator_skips_platform_mismatch_and_missing():
-    old = _bench_record(bls_platform="tpu")
-    new = _bench_record(bls_sigs_per_sec=1.0)  # 100x slower, but on cpu
-    del new["mxu_mode_speedup"]
-    rep = bench.compare_records(old, new)
-    assert rep["ok"]
-    skipped = {s["metric"] for s in rep["skipped"]}
-    assert "bls_sigs_per_sec" in skipped
-    assert "mxu_mode_speedup" in skipped
-
-
-def test_bench_comparator_unwraps_driver_records():
-    wrapped = {"n": 6, "rc": 0, "parsed": _bench_record()}
-    assert bench._unwrap_record(wrapped)["value"] == 10.0
-    assert bench._unwrap_record(_bench_record())["value"] == 10.0

@@ -8,6 +8,7 @@
   the constant-ladder counters with each program's static counts.
 - A node built on ``tpu`` compiles its stage programs after loading its
   registry's keys: its first multi-key verify compiles none of them.
+- Clearing the ring first records every device span watched before it.
 - Block import: the four new kinds nest in ``block_import``.
 - Profiler clock: a host span holds a ``lighthouse_tpu:<kind>``
   annotation of the same length in a JAX profile.
@@ -284,6 +285,30 @@ def test_device_span_of_a_failed_stage_still_ends(monkeypatch):
     (s,) = [s for s in tracing.snapshot() if s.kind == "bls_pairing"]
     assert s.attrs["error"] == "RuntimeError"
     assert s.parent_id == batch.span_id and s.end >= s.start
+
+
+def test_clear_lands_the_device_spans_watched_before_it(monkeypatch):
+    """A stage still running when the ring is cleared is recorded before
+    the clear returns: it cannot land among a later reader's spans."""
+    import threading
+
+    import jax
+
+    ready = threading.Event()
+    real = jax.block_until_ready
+
+    def slow(outputs):
+        ready.wait(10)
+        return real(outputs)
+
+    monkeypatch.setattr(jax, "block_until_ready", slow)
+    stage = tracing.device_span("bls_pairing")
+    stage.watch(jax.numpy.ones(2))
+    threading.Timer(0.2, ready.set).start()
+    tracing.clear()
+    assert stage.recorded.is_set()
+    tracing.wait_device_spans()
+    assert tracing.snapshot() == []
 
 
 # -- block import ------------------------------------------------------------

@@ -170,16 +170,33 @@ def test_native_bit_flip_in_batch_drops_it(tmp_path):
         flipped.unlink()
 
 
-def test_native_invalid_batch_payload_rejected(tmp_path):
+def _op(key: bytes, value: bytes) -> bytes:
+    """One put of a kv_write_batch payload: key and value lengths, then
+    both."""
+    return (len(key).to_bytes(4, "little") + len(value).to_bytes(4, "little")
+            + key + value)
+
+
+_ONE_OP = _op(b"a", b"1")
+
+
+@pytest.mark.parametrize("payload", [
+    b"\xff\xff\xff\x7f" + b"junk",                     # absurd op count
+    (2).to_bytes(4, "little") + _ONE_OP,                # fewer ops than said
+    (1).to_bytes(4, "little") + (100).to_bytes(4, "little")
+    + (1).to_bytes(4, "little") + b"ab",                # key past the end
+    (1).to_bytes(4, "little") + _ONE_OP + b"x",         # trailing bytes
+], ids=["absurd_count", "count_over_ops", "key_past_end", "trailing_bytes"])
+def test_native_invalid_batch_payload_rejected(tmp_path, payload):
     """kv_write_batch validates the payload BEFORE touching the log: a
     malformed frame returns an error and leaves the store unchanged."""
     s = _open(tmp_path / "kv.db")
     s.put(b"k", b"v")
     lib = s._lib
-    bogus = b"\xff\xff\xff\x7f" + b"junk"        # absurd op count
-    rc = lib.kv_write_batch(s._h, bogus, len(bogus), 0)
+    rc = lib.kv_write_batch(s._h, payload, len(payload), 0)
     assert rc != 0
     assert s.get(b"k") == b"v"
+    assert s.get(b"a") is None
     s.do_atomically([("put", b"k2", b"v2")])     # store still writable
     assert s.get(b"k2") == b"v2"
     s.close()

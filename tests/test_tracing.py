@@ -31,8 +31,7 @@ from lighthouse_tpu.api import metrics, metrics_defs  # noqa: E402
 from lighthouse_tpu.obs import report as obs_report  # noqa: E402
 from lighthouse_tpu.obs import tracing  # noqa: E402
 
-SRC_FILES = sorted((REPO / "lighthouse_tpu").rglob("*.py")) + \
-    [REPO / "bench.py"]
+SRC_FILES = sorted((REPO / "lighthouse_tpu").rglob("*.py"))
 
 
 # -- 1. tracing core ---------------------------------------------------------
@@ -169,6 +168,32 @@ def test_beacon_processor_work_propagates_trace():
     assert "db_write" in kinds
     pw = next(s for s in spans if s.kind == "processor_work")
     assert pw.attrs["work_kind"] == "STATUS"
+
+
+def test_wait_idle_returns_with_the_work_span_recorded(monkeypatch):
+    import time
+
+    from lighthouse_tpu.beacon_processor import (
+        BeaconProcessor, Work, WorkType,
+    )
+    record = tracing._record
+
+    def slow_record(s):
+        if s.kind == "processor_work":
+            time.sleep(0.3)
+        record(s)
+
+    monkeypatch.setattr(tracing, "_record", slow_record)
+    obs.clear()
+    proc = BeaconProcessor(num_workers=1)
+    proc.start()
+    try:
+        proc.submit(Work(WorkType.STATUS, lambda: None))
+        assert proc.wait_idle(timeout=10)
+        kinds = [s.kind for s in obs.snapshot()]
+    finally:
+        proc.stop()
+    assert kinds == ["processor_work"]
 
 
 def _fresh_harness(validators=32):
@@ -470,7 +495,7 @@ def test_start_timer_records_one_observation():
     assert "beacon_block_processing_db_write_seconds" in text
 
 
-# -- report CLI / bench plumbing ---------------------------------------------
+# -- report CLI ---------------------------------------------------------------
 
 def test_trace_report_cli_renders_table(tmp_path):
     obs.clear()
@@ -499,21 +524,6 @@ def test_trace_report_cli_rejects_garbage(tmp_path):
         [sys.executable, str(REPO / "tools" / "trace" / "report.py"),
          str(bad)], capture_output=True, text=True, timeout=60)
     assert out.returncode == 2
-
-
-def test_bench_trace_artifacts(tmp_path):
-    import bench
-    obs.clear()
-    with obs.span("bench_stage", stage="tree_hash_rep"):
-        pass
-    path = bench._write_trace_artifacts("tree_hash", str(tmp_path))
-    assert path is not None
-    doc = json.loads(Path(path).read_text())
-    assert doc["traceEvents"][0]["name"] == "bench_stage"
-    summary = json.loads(
-        (tmp_path / "BENCH_TRACE_tree_hash_summary.json").read_text())
-    assert "bench_stage" in summary["stages"]
-    assert "compiles" in summary["jax"]
 
 
 def test_tracing_http_endpoint_serves_chrome_trace():

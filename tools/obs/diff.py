@@ -1,20 +1,19 @@
 #!/usr/bin/env python
 """graftpath differential profile — where did the time go between runs.
 
-    python tools/obs/diff.py BENCH_TRACE_stf.main.json BENCH_TRACE_stf.json
+    python tools/obs/diff.py old.json new.json
     python tools/obs/diff.py --json old.json new.json
     python tools/obs/diff.py --top 8 old_capture.json new_capture.json
 
 Aligns two trace captures (Chrome trace-event documents from
-`/lighthouse/tracing` / `bench.py --trace`, the `{"data": [span...]}`
+`/lighthouse/tracing`, the `{"data": [span...]}`
 form of `/lighthouse/tracing/spans`, or whole flight-recorder dumps) by
 stage kind and attributes the wall-clock delta per stage: count, total
 and p95 in both captures, the total-ms delta, and each stage's share of
 the overall regression.  It then extracts both captures' critical paths
 (obs/critpath.py, stitched cross-node when the captures carry node
 attrs) and reports how the path itself moved — the stage whose
-self-time grew is the one `bench.py --against` is really complaining
-about.
+self-time grew is the one behind the regression.
 
 Exit codes: 0 report produced, 2 unreadable input.
 """

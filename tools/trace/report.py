@@ -7,8 +7,8 @@
     python tools/trace/report.py --since-slot 64 --kind block_pipeline t.json
     python tools/trace/report.py --critpath trace.json
 
-Accepts the Chrome trace-event document served by /lighthouse/tracing
-(or written by `bench.py --trace`), or the {"data": [span...]} form of
+Accepts the Chrome trace-event document served by /lighthouse/tracing,
+or the {"data": [span...]} form of
 /lighthouse/tracing/spans.  Prints count / p50 / p95 / max / total per
 stage, widest-total first.
 
